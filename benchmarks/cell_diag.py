@@ -21,12 +21,12 @@ def main() -> None:
         f"--xla_force_host_platform_device_count={args.devices}")
     import numpy as np
     import jax
-    from jax.sharding import Mesh
+
+    from repro.launch.mesh import make_mesh
 
     dims = [int(x) for x in args.mesh.split("x")]
     names = ("data", "model") if len(dims) == 2 else ("pod", "data", "model")
-    mesh = Mesh(np.array(jax.devices()[:int(np.prod(dims))]).reshape(dims),
-                names)
+    mesh = make_mesh(dims, names, jax.devices()[:int(np.prod(dims))])
 
     from repro.launch import dryrun
     dryrun._mesh = lambda mp: mesh

@@ -1,8 +1,8 @@
 """Mesh strategy benchmark: per-op shardmap dispatch and sharded serving.
 
-Runs on a FORCED 8-device CPU mesh (``--xla_force_host_platform_device_count``
-is set before jax initialises, so this script must be a fresh process), and
-measures two things:
+Runs on an 8-device CPU mesh (``JAX_PLATFORMS=cpu`` and
+``XLA_FLAGS=--xla_force_host_platform_device_count=8`` in the environment),
+and measures two things:
 
   ops     — the six tuned kernels dispatched through ``dpia-shardmap``
             (mesh-level DPIA strategies -> shard_map + collectives) vs the
@@ -20,7 +20,8 @@ real accelerators.  Asserts cover exactly those invariants (``--no-assert``
 to report only).
 
 Usage:
-  PYTHONPATH=src python benchmarks/mesh_bench.py [--smoke] [--out FILE]
+  JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+    PYTHONPATH=src python benchmarks/mesh_bench.py [--smoke] [--out FILE]
 
 Writes BENCH_mesh.json (``--out`` to override) and prints a summary.
 """
@@ -28,16 +29,13 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
 import time
 
-# must happen before jax initialises: an 8-device host platform
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+import jax
+import jax.numpy as jnp
+import numpy as np
 
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-import numpy as np  # noqa: E402
+from repro.launch.mesh import make_mesh
 
 
 def _best_of(fn, repeats: int = 5) -> float:
@@ -183,9 +181,9 @@ def main() -> None:
     n_dev = len(jax.devices())
     if n_dev < 8:
         raise SystemExit(f"mesh_bench needs 8 forced host devices, got "
-                         f"{n_dev} — run in a fresh process (XLA_FLAGS is "
-                         f"set at import, before jax initialises)")
-    mesh = jax.make_mesh((8,), ("data",))
+                         f"{n_dev} — run with JAX_PLATFORMS=cpu and "
+                         f"XLA_FLAGS=--xla_force_host_platform_device_count=8")
+    mesh = make_mesh((8,), ("data",))
     repeats = 2 if args.smoke else 5
 
     ops_doc = bench_ops(mesh, args.smoke, repeats)

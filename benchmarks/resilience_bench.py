@@ -330,6 +330,7 @@ def main() -> None:
     elif args.host_loss:
         t0 = time.perf_counter()
         import tempfile
+        from repro.launch.mesh import make_mesh
         from repro.serve.domains import SchedulerJournal
         from repro.serve.engine import ShardedEngine
         # 8 phase-local requests so both hosts' slots carry work when the
@@ -348,7 +349,7 @@ def main() -> None:
         dumps0 = len(obs.flight_dumps())
         degr0 = obs.counter("serve.degradations").value
         eng = ShardedEngine(model, params, max_seq=64, slots=8, chunk=4,
-                            min_bucket=8, mesh=jax.make_mesh((8,), ("data",)),
+                            min_bucket=8, mesh=make_mesh((8,), ("data",)),
                             hosts=2)
         clean = _drive(eng, f_reqs, fkey)
         assert all(r.state == "ok" for r in clean)
@@ -362,7 +363,7 @@ def main() -> None:
         jpath = args.journal_out or os.path.join(
             tempfile.mkdtemp(prefix="resil-bench-"), "journal.jsonl")
         eng = ShardedEngine(model, params, max_seq=64, slots=8, chunk=4,
-                            min_bucket=8, mesh=jax.make_mesh((8,), ("data",)),
+                            min_bucket=8, mesh=make_mesh((8,), ("data",)),
                             hosts=2, journal=jpath)
         with faults.inject("mesh.host_lost(host=1, after=3)") as plan:
             results = _drive(eng, f_reqs, fkey)

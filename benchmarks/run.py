@@ -101,7 +101,7 @@ def bench_kernels(csv_rows: List[str]) -> None:
 
 
 def bench_train_step(csv_rows: List[str]) -> None:
-    from jax.sharding import Mesh
+    from repro.launch.mesh import make_mesh
     from repro.models.common import ModelConfig
     from repro.models.transformer import Model
     from repro.train.step import (make_train_state, make_train_step,
@@ -111,7 +111,7 @@ def bench_train_step(csv_rows: List[str]) -> None:
                       d_model=512, n_heads=8, n_kv_heads=4, d_ff=1536,
                       vocab=8192, dtype="float32", remat=False, max_seq=128)
     model = Model(cfg)
-    mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"), jax.devices()[:1])
     state = make_train_state(model, jax.random.PRNGKey(0))
     st_spec = state_specs(state, mesh, cfg)
     _, jit_with, _ = make_train_step(model, mesh)

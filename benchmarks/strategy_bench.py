@@ -33,8 +33,6 @@ import os
 import tempfile
 import time
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 CORPUS = [
     ("dot", {"n": 1024}), ("dot", {"n": 2048}),
     ("asum", {"n": 1024}), ("asum", {"n": 2048}),

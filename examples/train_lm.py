@@ -10,11 +10,11 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh
 
 from repro.ckpt.manager import CheckpointManager
 from repro.data.pipeline import DataConfig, SyntheticLM
 from repro.ft.resilience import TrainLoop
+from repro.launch.mesh import make_mesh
 from repro.models.common import ModelConfig
 from repro.models.transformer import Model
 from repro.train.step import make_train_state, make_train_step, state_specs
@@ -36,7 +36,7 @@ def main():
     model = Model(cfg)
     print(f"params: {cfg.param_count()/1e6:.1f}M")
 
-    mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"), jax.devices()[:1])
     state = make_train_state(model, jax.random.PRNGKey(0))
     st_spec = state_specs(state, mesh, cfg)
     _, jit_with, _ = make_train_step(model, mesh, base_lr=6e-4,

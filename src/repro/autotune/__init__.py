@@ -27,7 +27,7 @@ from .api import (  # noqa: F401
 )
 from .cache import TuningCache, default_cache  # noqa: F401
 from .cost import (  # noqa: F401
-    HW_PRESETS, CostEstimate, HwModel, KvLayoutCost, estimate, hw_model,
-    kv_layout_cost, xla_cost,
+    PEAKS, CostEstimate, HwModel, KvLayoutCost, device_kind, estimate,
+    hw_model, kv_layout_cost, xla_cost,
 )
 from .space import Candidate, candidate_from_params, default_params, enumerate_space  # noqa: F401

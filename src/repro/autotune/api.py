@@ -27,7 +27,7 @@ from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Union
 
 from repro import obs
-from repro.compiler import Program
+from repro.compiler import Program, current_options
 from repro.core.dpia import phrases as P
 
 from . import measure as measure_mod
@@ -127,7 +127,8 @@ def tune(spec: Spec, *, backend: str = "jnp", dtype: str = "float32",
          mesh=None, layout: str = "dense", cache=None, measure: bool = True,
          top_k: int = 4, iters: int = 5, force: bool = False,
          verify: bool = False, arg_vars: Optional[List[P.Var]] = None,
-         strategies=None, **shape) -> TuneResult:
+         strategies=None, interpret: Optional[bool] = None,
+         **shape) -> TuneResult:
     """Pick the best strategy for ``spec`` at a concrete shape.
 
     ``spec`` is a kernel name ("dot", "asum", "scal", "matmul", "rmsnorm",
@@ -164,6 +165,10 @@ def tune(spec: Spec, *, backend: str = "jnp", dtype: str = "float32",
     ``strategy_trace``.  Every fresh tuning decision (with or without
     explicit strategies) serialises the winner's ``StrategyTrace`` into the
     cache record and the provenance log.
+
+    ``interpret`` is the Pallas mode the pick will run in (None: the active
+    ``compiler.options``).  With ``backend="pallas"`` and ``interpret``
+    False the space keeps only candidates that lower to TPU kernels.
     """
     from repro import mesh as mesh_mod
     c = _resolve_cache(cache)
@@ -258,6 +263,11 @@ def tune(spec: Spec, *, backend: str = "jnp", dtype: str = "float32",
                     default = None
             else:
                 cands = space_mod.enumerate_space(kernel, **shape)
+                if backend == "pallas" and not (
+                        current_options().interpret if interpret is None
+                        else interpret):
+                    cands = [c for c in cands
+                             if measure_mod.lowers_for_chip(c)]
                 try:
                     default = space_mod.candidate_from_params(
                         kernel, space_mod.default_params(kernel, **shape),
@@ -329,15 +339,18 @@ def tune(spec: Spec, *, backend: str = "jnp", dtype: str = "float32",
 
 def get_tuned(kernel: str, *, backend: str = "jnp", dtype: str = "float32",
               mesh=None, layout: str = "dense", cache=None,
+              interpret: Optional[bool] = None,
               **shape) -> Dict[str, object]:
     """Tuned params for a kernel/shape — cache hit or cheap analytic search.
 
-    ``mesh`` / ``layout`` as in :func:`tune`: the mesh descriptor and the
-    serving KV layout are both cache-key dimensions.  This is the
-    serving-path entry: it never compiles or measures, so a cold call
-    costs one pass of the analytic model and a hot call is a dict lookup."""
+    ``mesh`` / ``layout`` / ``interpret`` as in :func:`tune`: the mesh
+    descriptor and the serving KV layout are both cache-key dimensions.
+    This is the serving-path entry: it never compiles or measures, so a
+    cold call costs one pass of the analytic model (and, for Pallas on the
+    chip, one trace per candidate) and a hot call is a dict lookup."""
     res = tune(kernel, backend=backend, dtype=dtype, mesh=mesh,
-               layout=layout, cache=cache, measure=False, **shape)
+               layout=layout, cache=cache, measure=False,
+               interpret=interpret, **shape)
     return res.params
 
 
@@ -349,18 +362,19 @@ def pick_kv_layout(cfg, *, slots: int, max_seq: int, block_size: int = 16,
 
     Dense wins on raw decode traffic (no gather copy); paged wins the
     moment the dense resident cache blows the platform's HBM budget
-    (``cost.HwModel.hbm_capacity`` — per-backend presets, ``cost.HW_PRESETS``).
-    The decision is cached under kernel ``"kv_layout"`` keyed by the engine
-    shape + platform, so a serving engine built with ``kv_layout="auto"``
-    resolves it with one dict lookup.
+    (``cost.HwModel.hbm_capacity`` of the device's ``cost.PEAKS`` entry;
+    ``platform`` names a device kind, default this process's).  The
+    decision is cached under kernel ``"kv_layout"`` keyed by the engine
+    shape + device kind, so a serving engine built with
+    ``kv_layout="auto"`` resolves it with one dict lookup.
 
     Returns ``{"layout", "dense_bytes", "paged_bytes", "dense_s",
     "paged_s"}``."""
     from . import cost as cost_mod
     from repro.serve import paged as paged_mod
     c = _resolve_cache(cache)
-    hw = cost_mod.hw_model(platform)
-    plat = platform or __import__("jax").default_backend()
+    plat = platform or cost_mod.device_kind()
+    hw = cost_mod.hw_model(plat)
     layers = paged_mod._kv_layers(cfg)
     shape = {"slots": slots, "max_seq": max_seq, "block": block_size,
              "expected": int(expected_seq or 0), "layers": layers,

@@ -56,32 +56,42 @@ class HwModel:
 
 DEFAULT_HW = HwModel()
 
-# Per-platform presets (ROADMAP PR 1 follow-up: per-backend HW models).
-# The cpu preset is the tpu model uniformly slowed 5x — identical *ratios*,
-# so single-device strategy rankings are platform-stable — but with a host
-# RAM capacity; the gpu preset has genuinely different balance (higher
-# flops-per-byte) and an 80 GB HBM budget.  The capacity term is what the
-# KV-layout planner (:func:`pick_kv_layout`) ranks against.
-HW_PRESETS = {
-    "tpu": DEFAULT_HW,
+# The roofline of each device this repo knows, keyed by
+# ``jax.devices()[0].device_kind`` ("cpu" for a CPU host).
+#   * "TPU v5 lite" is TPU v5e.  Published peaks, Google Cloud documentation
+#     ("TPU v5e"): 197e12 bf16 FLOP/s, 819e9 B/s of HBM bandwidth, 16 GB of
+#     HBM, 1,600 Gbit/s of chip-to-chip interconnect.  The overhead terms
+#     keep DEFAULT_HW's unmeasured values.
+#   * "cpu" is DEFAULT_HW uniformly slowed 5x (identical ratios, so
+#     single-device rankings match the TPU-shaped default) with a host RAM
+#     capacity; it serves the tests and CPU rehearsals.
+# A device that is not in the table is an error, never a default.
+PEAKS = {
+    "TPU v5 lite": HwModel(peak_flops=197e12, hbm_bw=819e9,
+                           ici_bw=200e9, hbm_capacity=16e9),
     "cpu": HwModel(peak_flops=2.0e11, hbm_bw=2.0e10,
                    grid_overhead_s=1.0e-5, loop_overhead_s=2.5e-7,
                    ici_bw=1.0e10, collective_launch_s=2.5e-5,
                    hbm_capacity=64e9),
-    "gpu": HwModel(peak_flops=1.0e13, hbm_bw=2.0e12,
-                   grid_overhead_s=3.0e-6, loop_overhead_s=1.0e-7,
-                   ici_bw=2.0e11, collective_launch_s=3.0e-6,
-                   hbm_capacity=80e9),
 }
 
 
-def hw_model(platform: Optional[str] = None) -> HwModel:
-    """The HwModel preset for ``platform`` (``jax.default_backend()`` when
-    None); unknown platforms get the TPU-shaped default."""
-    if platform is None:
-        import jax
-        platform = jax.default_backend()
-    return HW_PRESETS.get(platform, DEFAULT_HW)
+def device_kind() -> str:
+    """The :data:`PEAKS` key of the process's first device."""
+    import jax
+    d = jax.devices()[0]
+    return "cpu" if d.platform == "cpu" else d.device_kind
+
+
+def hw_model(kind: Optional[str] = None) -> HwModel:
+    """The roofline for device kind ``kind`` (default: this process's);
+    raises for a device with no entry in :data:`PEAKS`."""
+    kind = kind or device_kind()
+    try:
+        return PEAKS[kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind {kind!r}; "
+                         f"known: {sorted(PEAKS)}") from None
 
 
 @dataclass

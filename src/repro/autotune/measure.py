@@ -57,6 +57,23 @@ def compile_candidate(cand: Candidate, backend: str = "jnp",
     return fn, args_for(prog.arg_vars)
 
 
+def lowers_for_chip(cand: Candidate) -> bool:
+    """Whether the candidate's Pallas kernels lower for the TPU: the program
+    is traced with ``interpret=False`` (nothing is compiled), and the
+    translation's NotImplementedError for an access Mosaic cannot tile
+    means it does not."""
+    import jax
+    prog = cand.program()
+    fn = prog.check().lower().compile("pallas", interpret=False, jit=False)
+    try:
+        jax.eval_shape(fn, *(jax.ShapeDtypeStruct(shape_of(v.t.d),
+                                                  dtype_of(v.t.d))
+                             for v in prog.arg_vars))
+    except NotImplementedError:
+        return False
+    return True
+
+
 def time_callable(fn, args, iters: int = 5, warmup: int = 1) -> float:
     """Median wall time in microseconds per call (after warmup/compile)."""
     import jax

@@ -169,6 +169,8 @@ class ExecutorCache:
             if os.path.exists(path):
                 continue
             meta = self._meta.get(key, {})
+            if "interpret" not in meta:
+                continue  # unknown interpret mode: a load could not honour it
             try:
                 prog_doc = fn.program.to_doc()
             except Exception:
@@ -177,7 +179,7 @@ class ExecutorCache:
                 "version": AOT_VERSION,
                 "key": key,
                 "backend": fn.backend,
-                "interpret": bool(meta.get("interpret", True)),
+                "interpret": bool(meta["interpret"]),
                 "jit": bool(meta.get("jit", True)),
                 "program": prog_doc,
             }
@@ -225,7 +227,10 @@ class ExecutorCache:
                 b = get_backend(doc["backend"])
                 kw = {}
                 if "interpret" in b.accepts:
-                    kw["interpret"] = bool(doc.get("interpret", True))
+                    if "interpret" not in doc:
+                        raise ValueError(f"AOT artefact {name} does not "
+                                         f"record its interpret mode")
+                    kw["interpret"] = bool(doc["interpret"])
                 with obs.span("executor_cache.aot_load", key=key,
                               backend=doc["backend"]):
                     fn = prog.compile(b, jit=bool(doc.get("jit", True)),

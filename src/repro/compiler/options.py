@@ -38,12 +38,20 @@ def _env_autotune() -> bool:
 def default_interpret() -> bool:
     """Whether Pallas kernels should default to interpret mode here: True
     only when the host platform is CPU (no Mosaic compiler), False on real
-    accelerators.  ``REPRO_INTERPRET=0|1`` overrides the probe."""
+    accelerators.  ``REPRO_INTERPRET=0|1`` overrides the probe on the CPU;
+    on an accelerator ``REPRO_INTERPRET=1`` is an error, so a kernel never
+    runs in the interpreter where the chip could run it."""
+    import jax
+    cpu = jax.default_backend() == "cpu"
     env = os.environ.get("REPRO_INTERPRET")
+    if env is not None and env != "0" and not cpu:
+        raise RuntimeError(
+            f"REPRO_INTERPRET={env} on platform {jax.default_backend()!r}: "
+            f"interpret mode is for CPU hosts only; unset it to compile the "
+            f"Pallas kernels for the device")
     if env is not None:
         return env != "0"
-    import jax
-    return jax.default_backend() == "cpu"
+    return cpu
 
 
 @dataclass(frozen=True)

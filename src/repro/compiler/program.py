@@ -108,7 +108,8 @@ class Program:
             params = space_mod.default_params(kernel, **shape)
         cand = space_mod.candidate_from_params(kernel, dict(params), **shape)
         expr, arg_vars = cand.build()
-        prog = cls(expr, arg_vars, kernel=kernel, shape=shape, name=kernel)
+        prog = cls(expr, arg_vars, kernel=kernel, shape=shape,
+                   name=f"{kernel}[{space_mod.params_key(params)}]")
         try:
             prog.strategy_trace = cand.trace_doc()
         except Exception:
@@ -270,6 +271,8 @@ class Program:
         call_kw = dict(backend_kw)
         if "interpret" in b.accepts:
             call_kw.setdefault("interpret", opts.interpret)
+        if "name" in b.accepts:
+            call_kw.setdefault("name", self.name)
         if "lowered" in b.accepts and self._cmd is not None:
             call_kw.setdefault("lowered", (self._cmd, self._out))
         if "check" in b.accepts:

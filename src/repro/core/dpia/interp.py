@@ -10,6 +10,13 @@ correctness (Theorem 5.1 as an executable property).  Values are pytrees:
 
 The interpreter is trace-compatible: it can run under jit/vmap, which is how
 ``map`` is given its parallel semantics here (vmap = the mathematical reading).
+
+A leaf may also be a :class:`Lazy` value that is read only when a
+computation consumes it.  The Pallas backend binds kernel inputs to lazy
+views of their refs, so the layout combinators (split, join, idx,
+transpose, asVector, asScalar) compose into index arithmetic on the ref and
+only the slice a computation needs is loaded: the paper's compilation of
+views to indices (section 6).
 """
 from __future__ import annotations
 
@@ -22,6 +29,47 @@ from . import phrases as P
 from .types import Arr, ExpT, Num, Pair, dtype_of, shape_of
 
 Env = Dict[str, object]
+
+
+class Lazy:
+    """A value read on demand.  ``load()`` gives the jax value; subclasses
+    that are array views also implement the layout combinators on their
+    leading axes (``split``, ``join``, ``index``, ``transpose``), each of
+    which returns another ``Lazy`` or, where the view cannot express the
+    result, the loaded jax value."""
+
+    def load(self):
+        raise NotImplementedError
+
+
+def force(v):
+    """``v`` with every :class:`Lazy` leaf loaded."""
+    return jax.tree_util.tree_map(
+        lambda l: l.load() if isinstance(l, Lazy) else l, v)
+
+
+def _split(l, n: int):
+    if isinstance(l, Lazy):
+        return l.split(n)
+    return l.reshape((l.shape[0] // n, n) + l.shape[1:])
+
+
+def _join(l):
+    if isinstance(l, Lazy):
+        return l.join()
+    return l.reshape((l.shape[0] * l.shape[1],) + l.shape[2:])
+
+
+def _index(l, i):
+    if isinstance(l, Lazy):
+        return l.index(i)
+    return l[force(i)]
+
+
+def _transpose(l):
+    if isinstance(l, Lazy):
+        return l.transpose()
+    return jnp.swapaxes(l, 0, 1)
 
 _UNOPS: Dict[str, Callable] = {
     "neg": lambda x: -x,
@@ -51,6 +99,7 @@ def interp(p: P.Phrase, env: Env, store: Optional[Env] = None):  # noqa: C901
     of the imperative backend (paper Fig. 6c).
     """
     rec = lambda q: interp(q, env, store)  # noqa: E731
+    val = lambda q: force(interp(q, env, store))  # noqa: E731
 
     if isinstance(p, P.Var):
         try:
@@ -70,11 +119,11 @@ def interp(p: P.Phrase, env: Env, store: Optional[Env] = None):  # noqa: C901
             return jnp.full(shp, p.value, dtype=dtype_of(p.d))
         return jnp.asarray(p.value, dtype=dtype_of(p.d))
     if isinstance(p, P.UnOp):
-        return _UNOPS[p.op](rec(p.e))
+        return _UNOPS[p.op](val(p.e))
     if isinstance(p, P.BinOp):
-        return _BINOPS[p.op](rec(p.a), rec(p.b))
+        return _BINOPS[p.op](val(p.a), val(p.b))
     if isinstance(p, P.Map):
-        xs = rec(p.e)
+        xs = val(p.e)
         d = P.exp_data(p.e)
         assert isinstance(d, Arr)
         x = P.Var(P.fresh("x"), ExpT(d.elem))
@@ -85,8 +134,8 @@ def interp(p: P.Phrase, env: Env, store: Optional[Env] = None):  # noqa: C901
 
         return jax.vmap(apply_elem)(xs)
     if isinstance(p, P.Reduce):
-        xs = rec(p.e)
-        init = rec(p.init)
+        xs = val(p.e)
+        init = val(p.init)
         d = P.exp_data(p.e)
         assert isinstance(d, Arr)
         x = P.Var(P.fresh("x"), ExpT(d.elem))
@@ -103,12 +152,9 @@ def interp(p: P.Phrase, env: Env, store: Optional[Env] = None):  # noqa: C901
         return (rec(p.a), rec(p.b))
     if isinstance(p, P.Split):
         v = rec(p.e)
-        return jax.tree_util.tree_map(
-            lambda l: l.reshape((l.shape[0] // p.n, p.n) + l.shape[1:]), v)
+        return jax.tree_util.tree_map(lambda l: _split(l, p.n), v)
     if isinstance(p, P.Join):
-        v = rec(p.e)
-        return jax.tree_util.tree_map(
-            lambda l: l.reshape((l.shape[0] * l.shape[1],) + l.shape[2:]), v)
+        return jax.tree_util.tree_map(_join, rec(p.e))
     if isinstance(p, P.PairE):
         return (rec(p.a), rec(p.b))
     if isinstance(p, P.Fst):
@@ -118,21 +164,18 @@ def interp(p: P.Phrase, env: Env, store: Optional[Env] = None):  # noqa: C901
     if isinstance(p, P.IdxE):
         v = rec(p.e)
         i = rec(p.i)
-        return jax.tree_util.tree_map(lambda l: l[i], v)
+        return jax.tree_util.tree_map(lambda l: _index(l, i), v)
     if isinstance(p, P.AsVector):
-        v = rec(p.e)
-        return v.reshape((v.shape[0] // p.w, p.w))
+        return _split(rec(p.e), p.w)
     if isinstance(p, P.AsScalar):
-        v = rec(p.e)
-        return v.reshape((v.shape[0] * v.shape[1],))
+        return _join(rec(p.e))
     if isinstance(p, P.Transpose):
-        v = rec(p.e)
-        return jax.tree_util.tree_map(lambda l: jnp.swapaxes(l, 0, 1), v)
+        return jax.tree_util.tree_map(_transpose, rec(p.e))
     if isinstance(p, P.DotBlock):
-        a, b = rec(p.a), rec(p.b)
+        a, b = val(p.a), val(p.b)
         return jnp.matmul(a, b, preferred_element_type=p.acc_dtype)
     if isinstance(p, P.FullReduce):
-        v = rec(p.e)
+        v = val(p.e)
         return jnp.sum(v) if p.op == "add" else jnp.max(v)
     if isinstance(p, P.ToMem):
         return rec(p.e)

@@ -122,8 +122,11 @@ def fold_acc(a: P.Phrase, idxs: List, value, eval_i, leaf):  # noqa: C901
         m = a.m
         if len(idxs) >= 2:
             i, j, rest = idxs[0], idxs[1], idxs[2:]
-            if isinstance(i, tuple) or isinstance(j, tuple):
+            if isinstance(i, tuple):
                 raise TypeError("joinAcc: mixed slice/index writes unsupported")
+            if isinstance(j, tuple):  # a slice within chunk i
+                return fold_acc(a.a, [("ds", i * m + j[1], j[2])] + rest,
+                                value, eval_i, leaf)
             return fold_acc(a.a, [i * m + j] + rest, value, eval_i, leaf)
         if len(idxs) == 1:
             i = idxs[0]

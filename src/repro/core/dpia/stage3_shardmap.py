@@ -21,12 +21,11 @@ broadcasts the small ones, exactly the data-parallel reading of the term.
 from __future__ import annotations
 
 from functools import partial
-from typing import Callable, Sequence
+from typing import Callable, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as PS
-from jax.experimental.shard_map import shard_map
 
 from . import phrases as P
 from .types import Arr, ExpT
@@ -61,13 +60,16 @@ def _chunk_expr(e: P.Phrase, c: int):
 
 def compile_expr_shardmap(expr: P.Phrase, arg_vars: Sequence[P.Var],
                           mesh: Mesh, *, inner: str = "jnp",
-                          check: bool = True) -> Callable:
-    """Compile a mesh-level functional strategy to a shard_map'd callable."""
+                          check: bool = True,
+                          interpret: Optional[bool] = None) -> Callable:
+    """Compile a mesh-level functional strategy to a shard_map'd callable;
+    ``interpret`` goes to the inner Pallas backend."""
     from . import stage3_jnp, stage3_pallas
 
     def compile_inner(e, vs):
         if inner == "pallas":
-            return stage3_pallas.compile_expr_pallas(e, vs, check=check)
+            return stage3_pallas.compile_expr_pallas(
+                e, vs, check=check, interpret=interpret)
         return stage3_jnp.compile_expr(e, vs, check=check)
 
     names = [v.name for v in arg_vars]
@@ -121,8 +123,8 @@ def compile_expr_shardmap(expr: P.Phrase, arg_vars: Sequence[P.Var],
             return jax.lax.psum(part, ax) if op == "add" \
                 else jax.lax.pmax(part, ax)
 
-        sm = shard_map(shard_fn, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_rep=False)
+        sm = jax.shard_map(shard_fn, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
         order = [v.name for v, _ in pairs] + [v.name for v in extras]
 
         def fn(*args):
@@ -166,8 +168,8 @@ def compile_expr_shardmap(expr: P.Phrase, arg_vars: Sequence[P.Var],
                 out = jax.tree_util.tree_map(lambda l: l[None], out)
             return out
 
-        sm = shard_map(shard_fn, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_rep=False)
+        sm = jax.shard_map(shard_fn, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
         order = [v.name for v, _ in pairs] + [v.name for v in extras]
 
         def fn(*args):
@@ -192,7 +194,7 @@ from repro.compiler.backends import register_backend as _register  # noqa: E402
 
 _register(_Backend(
     name="shardmap", compile=compile_expr_shardmap,
-    accepts=("mesh", "inner", "check"), requires=("mesh",),
+    accepts=("mesh", "inner", "check", "interpret"), requires=("mesh",),
     description="mesh-level strategies -> shard_map + collectives (pass "
                 "mesh=, optional inner='jnp'|'pallas')"),
     aliases=("dpia-shardmap",), overwrite=True)

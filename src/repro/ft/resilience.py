@@ -125,7 +125,8 @@ def elastic_remesh(preferred, axis_names=None):
     if not axes:
         raise ValueError(f"elastic_remesh needs at least one axis, got "
                          f"{preferred!r}")
-    return jax.make_mesh(tuple(axes.values()), tuple(axes.keys()))
+    from repro.launch.mesh import make_mesh
+    return make_mesh(tuple(axes.values()), tuple(axes.keys()))
 
 
 class TrainLoop:

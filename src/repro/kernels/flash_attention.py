@@ -26,6 +26,10 @@ from jax.experimental import pallas as pl
 from repro.compiler.options import default_interpret
 
 NEG_INF = -1e30
+# Every grid step holds its kv-head's whole K and V sequence in VMEM, double
+# buffered, inside Mosaic's default 16 MiB scoped limit (the rest is left
+# for the q/o blocks and the score tiles).  A KV-tiled grid lifts this.
+KV_VMEM_BYTES = 12 * 2 ** 20
 
 
 def _fa_kernel(q_ref, k_ref, v_ref, o_ref, *, bk: int, sk: int, scale: float,
@@ -82,6 +86,13 @@ def flash_attention(q, k, v, *, causal: bool = True, scale: float | None = None,
     bq = min(bq, sq)
     bk = min(bk, sk)
     assert sq % bq == 0 and sk % bk == 0
+    kv_bytes = 2 * 2 * sk * d * jnp.dtype(k.dtype).itemsize
+    if not interpret and kv_bytes > KV_VMEM_BYTES:
+        raise ValueError(
+            f"flash_attention: K and V blocks of {sk} x {d} "
+            f"{jnp.dtype(k.dtype).name} need {kv_bytes} bytes of VMEM "
+            f"double-buffered, over the {KV_VMEM_BYTES}-byte budget; the "
+            f"kernel keeps a kv-head's whole sequence in VMEM")
     scale_val = float(scale) if scale is not None else float(1.0 / (d ** 0.5))
 
     kernel = functools.partial(

@@ -12,12 +12,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = lambda shape, dt: pltpu.VMEM(shape, dt)  # noqa: E731
-except Exception:  # pragma: no cover
-    _VMEM = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt)  # noqa: E731
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _matmul_kernel(a_ref, b_ref, o_ref, acc_ref, *, n_k: int):
@@ -62,6 +57,6 @@ def matmul(a, b, *, bm: int = 128, bn: int = 128, bk: int = 128,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-        scratch_shapes=[_VMEM((bm, bn), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
     )(a, b)

@@ -152,7 +152,8 @@ def _tuned(kernel: str, backend: str, opts: CompileOptions,
     try:
         params = autotune.get_tuned(kernel, backend=backend, mesh=mesh_desc,
                                     cache=opts.tuning_cache,
-                                    layout=opts.kv_layout, **shape)
+                                    layout=opts.kv_layout,
+                                    interpret=bool(opts.interpret), **shape)
     except Exception as e:  # never let tuning break the op itself
         params = None
         _warn_once(("tune", kernel, backend),

@@ -22,12 +22,11 @@ import numpy as np
 
 def _mesh(multi_pod: bool):
     import jax
-    from jax.sharding import Mesh
+    from repro.launch.mesh import make_mesh
     if multi_pod:
-        devs = np.array(jax.devices()[:512]).reshape(2, 16, 16)
-        return Mesh(devs, ("pod", "data", "model"))
-    devs = np.array(jax.devices()[:256]).reshape(16, 16)
-    return Mesh(devs, ("data", "model"))
+        return make_mesh((2, 16, 16), ("pod", "data", "model"),
+                         jax.devices()[:512])
+    return make_mesh((16, 16), ("data", "model"), jax.devices()[:256])
 
 
 def lower_cell(arch: str, shape: str, multi_pod: bool) -> Dict:

@@ -29,15 +29,17 @@ def main() -> None:
                     help="mesh data-axis size; 0 = all devices")
     args = ap.parse_args()
 
+    from repro.launch import compile_cache
+    compile_cache.enable()
+
     import jax
     import jax.numpy as jnp
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS
-    import numpy as np
 
     from repro.ckpt.manager import CheckpointManager
     from repro.configs import config, smoke_config
     from repro.data.pipeline import DataConfig, SyntheticLM
     from repro.ft.resilience import TrainLoop
+    from repro.launch.mesh import make_mesh
     from repro.models.transformer import Model
     from repro.train.step import (make_train_state, make_train_step,
                                   state_specs)
@@ -51,8 +53,7 @@ def main() -> None:
 
     n_dev = len(jax.devices())
     nd = args.data_axis or n_dev
-    mesh = Mesh(np.array(jax.devices()[:nd]).reshape(nd, 1),
-                ("data", "model"))
+    mesh = make_mesh((nd, 1), ("data", "model"), jax.devices()[:nd])
     log.info("arch=%s params=%.2fM mesh=%s", cfg.name,
              cfg.param_count() / 1e6 if args.smoke else
              cfg.param_count() / 1e6, dict(mesh.shape))

@@ -22,7 +22,6 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as PS
 
 from .common import ModelConfig
@@ -128,7 +127,7 @@ def moe_ep(p: MoeParams, cfg: ModelConfig, x) -> Tuple[jax.Array, jax.Array]:
         return out.reshape(bl, sl, d).astype(xl.dtype), aux
 
     dp_spec = dp if dp else None
-    sm = shard_map(
+    sm = jax.shard_map(
         body, mesh=mesh,
         in_specs=(PS(dp_spec, "model", None),        # x: batch x seq(SP) x d
                   PS(None, None),                    # router replicated
@@ -136,6 +135,6 @@ def moe_ep(p: MoeParams, cfg: ModelConfig, x) -> Tuple[jax.Array, jax.Array]:
                   PS("model", None, None),
                   PS("model", None, None)),
         out_specs=(PS(dp_spec, "model", None), PS()),
-        check_rep=False)
+        check_vma=False)
     out, aux = sm(x, p.router, p.w_gate, p.w_up, p.w_down)
     return out, aux

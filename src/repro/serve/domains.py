@@ -194,9 +194,9 @@ class FailureDomains:
     def shrunk_mesh(self):
         """A fresh single-axis Mesh over the surviving devices, in original
         axis order — what the engine re-places its state onto."""
-        import jax
+        from repro.launch.mesh import make_mesh
         devs = [self._devices[p] for p in self.alive_positions()]
-        return jax.sharding.Mesh(np.asarray(devs), (self.axis,))
+        return make_mesh((len(devs),), (self.axis,), devs)
 
     # -- detection -----------------------------------------------------------
 

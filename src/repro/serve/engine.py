@@ -653,7 +653,8 @@ class ContinuousEngine(_EngineBase):
     warm traffic never recompiles.
 
     Output is token-identical to :class:`BatchedEngine` on the same
-    requests/key for every model family: per-request PRNG streams and
+    requests/key for every model family where numerics do not depend on
+    batch shape (float32 on the CPU): per-request PRNG streams and
     padding-invariant prefill (attention by causal masking, ssm/hybrid by
     masked recurrent-state updates) make the tokens a function of the
     request alone.
@@ -1317,8 +1318,13 @@ class ShardedEngine(ContinuousEngine):
     each device decodes ``slots / mesh.shape[axis]`` lanes and no collective
     appears in the hot loop (per-request work never crosses shards).  That
     also makes the engine token-identical to the unsharded
-    :class:`ContinuousEngine`: the per-row computation is bitwise the same,
-    only its placement changes — strategy preservation at the serving level.
+    :class:`ContinuousEngine` wherever the per-row computation is bitwise
+    the same — float32 on the CPU: only its placement changes, strategy
+    preservation at the serving level.  In bf16 on a TPU it is
+    token-identical to an unsharded engine with the same per-device batch
+    (``slots / mesh.shape[axis]`` slots); against a wider one, greedy
+    tokens can part where the top two logits are about a bf16 step apart.
+    ``chip_smoke.py --chips 4`` checks both.
 
     Admission prefill still runs batch=1 (replicated) and inserts the slot
     cache into the sharded engine cache; shapes and shardings are closed
@@ -1358,6 +1364,13 @@ class ShardedEngine(ContinuousEngine):
             raise ValueError(
                 "ShardedEngine needs a mesh: pass mesh=... or set the "
                 "process mesh context (repro.sharding.ctx.set_mesh)")
+        from repro.launch.mesh import has_explicit_axes
+        if has_explicit_axes(mesh):
+            raise ValueError(
+                f"ShardedEngine needs a mesh with Auto axes, got axis types "
+                f"{tuple(str(t) for t in mesh.axis_types)} (jax.make_mesh "
+                f"makes Explicit axes by default); build it with "
+                f"repro.launch.mesh.make_mesh")
         if mesh_axis not in mesh.shape:
             raise ValueError(f"mesh axis {mesh_axis!r} not in mesh axes "
                              f"{list(mesh.shape)}")

@@ -85,6 +85,34 @@ class TestOptions:
         assert opts.backend == "xla"
         assert opts.interpret is True
 
+    def test_interpret_env_is_cpu_only(self, monkeypatch):
+        """REPRO_INTERPRET=1 may pick interpret mode on the CPU only; on an
+        accelerator it is an error, never a silent interpreter."""
+        import jax
+        monkeypatch.setenv("REPRO_INTERPRET", "1")
+        assert compiler.default_interpret() is True
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        with pytest.raises(RuntimeError, match="CPU hosts only"):
+            compiler.default_interpret()
+        monkeypatch.setenv("REPRO_INTERPRET", "0")
+        assert compiler.default_interpret() is False
+
+    def test_aot_artefact_must_record_interpret(self, rng, tmp_path):
+        from repro.compiler import executors
+        from repro.ft import artefacts
+        store = executors.ExecutorCache()
+        prog = compiler.Program.from_kernel("dot", n=256)
+        store.put("dot-key", prog.compile("pallas"), meta={"interpret": True})
+        assert store.save_aot(str(tmp_path)) == 1
+        (name,) = [n for n in __import__("os").listdir(tmp_path)
+                   if n.endswith(".json")]
+        path = str(tmp_path / name)
+        doc = artefacts.load_json(path)
+        doc.pop("checksum", None)
+        doc.pop("interpret")
+        artefacts.save_json(path, doc)
+        assert executors.ExecutorCache().load_aot(str(tmp_path)) == 0
+
     def test_validation(self):
         with pytest.raises(ValueError, match="valid backends"):
             compiler.CompileOptions(backend="garbage")

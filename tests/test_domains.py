@@ -12,6 +12,7 @@ import numpy as np
 import pytest
 
 from repro.ft import artefacts
+from repro.launch.mesh import make_mesh
 from repro.mesh import strategy as ms
 from repro.models.common import ModelConfig
 from repro.models.transformer import Model
@@ -90,20 +91,20 @@ class TestPartition:
             FailureDomains.slots_for(groups, [True, False], 0, 7)
 
     def test_single_process_mesh_partitions_by_hosts_arg(self):
-        mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:1]), ("data",))
+        mesh = make_mesh((1,), ("data",), jax.devices()[:1])
         dom = FailureDomains(mesh, hosts=1)
         assert dom.n_hosts == 1
         assert dom.alive_positions() == [0]
         assert dom.describe()["losses"] == 0
 
     def test_all_hosts_lost_is_unservable(self):
-        mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:1]), ("data",))
+        mesh = make_mesh((1,), ("data",), jax.devices()[:1])
         dom = FailureDomains(mesh, hosts=1)
         with pytest.raises(RuntimeError, match="all 1 hosts lost"):
             dom.mark_lost(0)
 
     def test_mark_lost_idempotent_and_counts(self):
-        mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:1]), ("data",))
+        mesh = make_mesh((1,), ("data",), jax.devices()[:1])
         dom = FailureDomains(mesh, hosts=1)
         dom.groups = FailureDomains.partition(4, 2)   # pretend 2 hosts
         dom.alive = [True, True]
@@ -113,12 +114,12 @@ class TestPartition:
         assert dom.alive_hosts() == [0]
 
     def test_poll_is_none_without_fault_plan(self):
-        mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:1]), ("data",))
+        mesh = make_mesh((1,), ("data",), jax.devices()[:1])
         dom = FailureDomains(mesh, hosts=1)
         assert dom.poll() is None
 
     def test_slow_escalates_to_lost_at_threshold(self):
-        mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:1]), ("data",))
+        mesh = make_mesh((1,), ("data",), jax.devices()[:1])
         dom = FailureDomains(mesh, hosts=1, slow_threshold=3)
         with faults.inject("mesh.host_slow(host=0, times=3, value=0.01)"):
             e1 = dom.poll()
@@ -129,7 +130,7 @@ class TestPartition:
         assert "escalated" in e3.cause
 
     def test_collective_timeout_names_presumed_host(self):
-        mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:1]), ("data",))
+        mesh = make_mesh((1,), ("data",), jax.devices()[:1])
         dom = FailureDomains(mesh, hosts=1)
         dom.groups = FailureDomains.partition(4, 2)
         dom.alive = [True, True]
@@ -463,6 +464,7 @@ from repro.models.common import ModelConfig
 from repro.models.transformer import Model
 from repro.serve.engine import ContinuousEngine, ShardedEngine, Request
 from repro.serve.domains import SchedulerJournal, replay
+from repro.launch.mesh import make_mesh
 from repro.testing import faults
 from repro import obs
 
@@ -486,7 +488,7 @@ oracle = ContinuousEngine(model, params, max_seq=64, slots=8,
                           chunk=4).run(reqs(), key=key)
 
 def mk_sharded(**kw):
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     return ShardedEngine(model, params, max_seq=64, slots=8, chunk=4,
                          mesh=mesh, hosts=2, **kw)
 """

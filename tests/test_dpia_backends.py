@@ -1,5 +1,6 @@
 """Backend equivalence: Pallas (interpret) and hoisting vs the jnp backend and
 the functional oracle; mesh backend in a subprocess (needs >1 device)."""
+import re
 import subprocess
 import sys
 
@@ -60,6 +61,107 @@ class TestPallasBackend:
         both_backends(e, [alpha, xs], args)
 
 
+class TestPallasPlacement:
+    """Views compile to indices, the grid-level split to BlockSpecs."""
+
+    @staticmethod
+    def _spy(monkeypatch):
+        from jax.experimental import pallas as pl
+        calls = []
+        real = pl.pallas_call
+
+        def spy(kernel, **kw):
+            calls.append(kw)
+            return real(kernel, **kw)
+        monkeypatch.setattr(stage3_pallas.pl, "pallas_call", spy)
+        return calls
+
+    def test_grid_split_becomes_blockspec(self, rng, monkeypatch):
+        calls = self._spy(monkeypatch)
+        expr, argv = dpia_blas.strategy_dot(8192, block=2048)
+        args = (jnp.asarray(rng.randn(8192), "float32"),
+                jnp.asarray(rng.randn(8192), "float32"))
+        both_backends(expr, argv, args)
+        (kw,) = calls
+        assert [s.block_shape for s in kw["in_specs"]] == [(2048,), (2048,)]
+        # one partial per grid step, stored element by element: SMEM
+        assert kw["out_specs"][0].memory_space == stage3_pallas.pltpu.SMEM
+
+    def test_untileable_block_stays_whole(self, rng, monkeypatch):
+        calls = self._spy(monkeypatch)
+        expr, argv = dpia_blas.strategy_scal(512, block=64)
+        both_backends(expr, argv,
+                      (jnp.float32(3.5), jnp.asarray(rng.randn(512),
+                                                     "float32")))
+        (kw,) = calls
+        # a 64-element 1-D block is not a whole (8, 128) tile
+        assert kw["in_specs"][1].block_shape == (512,)
+
+    def test_oversized_operand_read_by_dma(self, rng, monkeypatch):
+        calls = self._spy(monkeypatch)
+        monkeypatch.setattr(stage3_pallas, "VMEM_OPERAND_BYTES", 64 * 1024)
+        expr, argv = dpia_blas.strategy_matmul(64, 256, 128, bm=16, bk=128)
+        args = (jnp.asarray(rng.randn(64, 256), "float32"),
+                jnp.asarray(rng.randn(256, 128), "float32"))
+        both_backends(expr, argv, args)
+        (kw,) = calls
+        a_spec, b_spec = kw["in_specs"]
+        assert a_spec.block_shape == (16, 256)
+        assert b_spec.memory_space == stage3_pallas.pl.ANY
+        assert any(getattr(s, "shape", None) == (128, 128)
+                   for s in kw["scratch_shapes"])
+
+
+    def test_vector_slices_within_grid_blocks(self, rng):
+        """scal's vector strategy writes lane-width slices inside each grid
+        block: joinAcc of a block index and a slice."""
+        from repro import compiler
+        fn = compiler.Program.from_kernel(
+            "scal", n=1024, params={"block": 256, "vector": 128}
+        ).check().lower().compile("pallas")
+        x = jnp.asarray(rng.randn(1024), "float32")
+        np.testing.assert_allclose(np.asarray(fn(jnp.float32(2.5), x)),
+                                   2.5 * np.asarray(x), rtol=1e-6)
+
+    @pytest.mark.parametrize("kernel,params,shape,args", [
+        ("dot", {"block": 1024, "leaf": "seq"}, {"n": 8192},
+         [(8192,), (8192,)]),
+        ("scal", {"block": 1024, "vector": 128}, {"n": 8192},
+         [(), (8192,)]),
+        ("matmul", {"bm": 128, "bk": 64}, {"m": 256, "k": 256, "n": 256},
+         [(256, 256), (256, 256)]),
+    ], ids=["dot-seq-leaf", "scal-lane-vector", "matmul-narrow-k"])
+    def test_untileable_strategy_refused_for_the_chip(self, kernel, params,
+                                                      shape, args):
+        """Accesses Mosaic cannot tile raise, naming the op and its params,
+        instead of failing inside Mosaic; interpret mode still runs them."""
+        from repro import compiler
+        from repro.autotune import space
+        prog = compiler.Program.from_kernel(kernel, params=params, **shape)
+        sds = [jax.ShapeDtypeStruct(s, jnp.float32) for s in args]
+        fn = prog.check().lower().compile("pallas", interpret=False,
+                                          jit=False)
+        name = re.escape(f"{kernel}[{space.params_key(params)}]")
+        with pytest.raises(NotImplementedError, match=name):
+            jax.eval_shape(fn, *sds)
+        jax.eval_shape(prog.compile("pallas", interpret=True, jit=False),
+                       *sds)
+
+    def test_tuner_keeps_only_lowering_candidates_for_the_chip(self,
+                                                               tuning_cache):
+        from repro import autotune
+        from repro.autotune import measure, space
+        full = autotune.tune("dot", backend="pallas", measure=False,
+                             interpret=True, force=True, cache=tuning_cache,
+                             n=8192)
+        chip = autotune.tune("dot", backend="pallas", measure=False,
+                             interpret=False, force=True,
+                             cache=tuning_cache, n=8192)
+        assert 0 < chip.n_candidates < full.n_candidates
+        assert measure.lowers_for_chip(
+            space.candidate_from_params("dot", chip.params, n=8192))
+
+
 class TestHoist:
     def test_paper_64_example_semantics(self, rng):
         """Section 6.4: hoisting multiplies extents and preserves semantics."""
@@ -98,8 +200,9 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from repro.core.dpia import interp, stage3_shardmap
 from repro.kernels import dpia_blas
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 expr, argv = dpia_blas.mesh_dot(8 * 64, "data", 8, block=64)
 rng = np.random.RandomState(0)
 ax = jnp.asarray(rng.randn(512), "float32")

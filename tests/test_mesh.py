@@ -12,6 +12,8 @@ from repro import autotune, compiler
 from repro import mesh as mesh_mod
 from repro.autotune import cost
 from repro.kernels import dpia_blas, ops
+from repro.launch import mesh as mesh_mod_launch
+from repro.launch.mesh import make_mesh
 
 
 # ---------------------------------------------------------------------------
@@ -25,7 +27,7 @@ class TestDescriptor:
         assert mesh_mod.parse_descriptor("") == {}
 
     def test_mesh_object_round_trip(self):
-        m = jax.make_mesh((1,), ("data",))
+        m = make_mesh((1,), ("data",))
         d = mesh_mod.descriptor(m)
         assert d == "data=1"
         assert mesh_mod.parse_descriptor(d) == {"data": 1}
@@ -201,7 +203,7 @@ class TestMeshResolution:
     def test_options_carry_mesh_to_shardmap_compile(self, rng):
         """Program.compile('shardmap') resolves the mesh from the active
         options scope — on a 1-device mesh, right here in-process."""
-        m1 = jax.make_mesh((1,), ("data",))
+        m1 = make_mesh((1,), ("data",))
         expr, argv = mesh_mod.mesh_dot(64, "data", 1)
         x = jnp.asarray(rng.randn(64), "float32")
         y = jnp.asarray(rng.randn(64), "float32")
@@ -232,6 +234,17 @@ class TestMeshResolution:
         with pytest.raises(ValueError, match="needs a mesh"):
             ShardedEngine(object(), {}, mesh=None)
 
+    def test_sharded_engine_rejects_explicit_axes(self):
+        """jax.make_mesh makes Explicit axes; the engine's NamedSharding
+        placement needs Auto ones, and says so before any jit."""
+        from jax.sharding import AxisType
+        from repro.serve.engine import ShardedEngine
+        m = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Explicit,))
+        with pytest.raises(ValueError, match="Auto axes.*make_mesh"):
+            ShardedEngine(object(), {}, mesh=m)
+        assert not mesh_mod_launch.has_explicit_axes(make_mesh((1,),
+                                                               ("data",)))
+
 
 # ---------------------------------------------------------------------------
 # forced-8-device acceptance (subprocesses; see conftest.forced_devices)
@@ -241,8 +254,9 @@ SHARD_OPS = r"""
 import jax, jax.numpy as jnp, numpy as np
 from repro import compiler
 from repro.kernels import ops
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 rng = np.random.RandomState(0)
 x = jnp.asarray(rng.randn(1024), "float32")
 y = jnp.asarray(rng.randn(1024), "float32")
@@ -304,6 +318,7 @@ import jax, jax.numpy as jnp, numpy as np
 from repro.models.common import ModelConfig
 from repro.models.transformer import Model
 from repro.serve.engine import ContinuousEngine, ShardedEngine, Request
+from repro.launch.mesh import make_mesh
 
 cfg = ModelConfig(name="t", family="dense", n_layers=2, d_model=32,
                   n_heads=4, n_kv_heads=2, d_ff=64, vocab=128, max_seq=64)
@@ -318,7 +333,7 @@ def reqs():
                     max_new_tokens=m, temperature=t, top_k=k)
             for l, m, t, k in spec]
 
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 key = jax.random.PRNGKey(7)
 cont = ContinuousEngine(model, params, max_seq=64, slots=8, chunk=4)
 want = cont.run(reqs(), key=key)

@@ -13,14 +13,14 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import dataclasses
 import numpy as np, jax, jax.numpy as jnp
-from jax.sharding import Mesh
+from repro.launch.mesh import make_mesh
 from repro.configs import smoke_config
 from repro.models import ffn, moe_ep
 from repro.sharding import ctx
 
 cfg = smoke_config("dbrx_132b")                       # 4 experts top-2
 cfg = dataclasses.replace(cfg, moe_capacity_factor=8.0)  # no drops
-mesh = Mesh(np.array(jax.devices()).reshape(2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"), jax.devices())
 ctx.set_mesh(mesh)
 assert moe_ep.applicable(cfg, mesh)
 

@@ -325,11 +325,14 @@ class TestPagedModelLevel:
 
 class TestKvLayoutPlanner:
     def test_presets_exist_per_platform(self):
-        from repro.autotune import HW_PRESETS, hw_model
-        assert set(HW_PRESETS) == {"cpu", "gpu", "tpu"}
-        assert hw_model("cpu").hbm_bw < hw_model("gpu").hbm_bw
-        assert hw_model("no-such-platform") is hw_model("tpu")
-        assert hw_model() in HW_PRESETS.values()
+        from repro.autotune import PEAKS, hw_model
+        v5e = hw_model("TPU v5 lite")
+        assert (v5e.peak_flops, v5e.hbm_bw, v5e.hbm_capacity) == \
+            (197e12, 819e9, 16e9)
+        assert hw_model("cpu").hbm_bw < v5e.hbm_bw
+        with pytest.raises(ValueError, match="no published peaks"):
+            hw_model("no-such-platform")
+        assert hw_model() is PEAKS["cpu"]
 
     def test_paged_shrinks_resident_never_traffic(self):
         from repro.autotune import kv_layout_cost
@@ -345,13 +348,13 @@ class TestKvLayoutPlanner:
         cfg, _, _ = dense_model
         cpath = str(tmp_path / "plan.json")
         small = autotune.pick_kv_layout(cfg, slots=2, max_seq=64,
-                                        platform="tpu", cache=cpath)
+                                        platform="TPU v5 lite", cache=cpath)
         assert small["layout"] == "dense"
         big_cfg = tiny_cfg(name="paged-big", n_layers=32, d_model=4096,
                            n_heads=32, n_kv_heads=8, max_seq=131072)
         big = autotune.pick_kv_layout(big_cfg, slots=256, max_seq=131072,
-                                      expected_seq=4096, platform="tpu",
-                                      cache=cpath)
+                                      expected_seq=4096,
+                                      platform="TPU v5 lite", cache=cpath)
         assert big["layout"] == "paged"
         assert big["dense_bytes"] > big["paged_bytes"]
 
@@ -360,9 +363,9 @@ class TestKvLayoutPlanner:
         cfg, _, _ = dense_model
         cpath = str(tmp_path / "tune.json")
         a = autotune.pick_kv_layout(cfg, slots=2, max_seq=64,
-                                    platform="tpu", cache=cpath)
+                                    platform="TPU v5 lite", cache=cpath)
         b = autotune.pick_kv_layout(cfg, slots=2, max_seq=64,
-                                    platform="tpu", cache=cpath)
+                                    platform="TPU v5 lite", cache=cpath)
         assert a == b
         cache = autotune.TuningCache(cpath)
         assert any(k.startswith("kv_layout|") for k in cache.keys())

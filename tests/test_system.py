@@ -13,16 +13,15 @@ ENV = {"PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "HOME": "/root",
 SHARDED_LOWER = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=16"
-import numpy as np, jax
-from jax.sharding import Mesh
+import jax
 from repro.launch import dryrun
+from repro.launch.mesh import make_mesh
 
 def mini_mesh(multi_pod):
     if multi_pod:
-        return Mesh(np.array(jax.devices()[:16]).reshape(2, 2, 4),
-                    ("pod", "data", "model"))
-    return Mesh(np.array(jax.devices()[:16]).reshape(4, 4),
-                ("data", "model"))
+        return make_mesh((2, 2, 4), ("pod", "data", "model"),
+                         jax.devices()[:16])
+    return make_mesh((4, 4), ("data", "model"), jax.devices()[:16])
 
 dryrun._mesh = mini_mesh
 rec = dryrun.lower_cell("stablelm_1_6b", "train_4k", False)
@@ -61,3 +60,37 @@ def test_examples_quickstart():
     r = subprocess.run([sys.executable, "examples/quickstart.py"],
                        capture_output=True, text=True, timeout=600, env=ENV)
     assert "== oracle OK" in r.stdout, r.stdout[-1500:] + r.stderr[-1500:]
+
+
+CACHE_PROBE = r"""
+import os, sys
+import jax, jax.numpy as jnp
+from repro.launch import compile_cache
+print("DIR", compile_cache.enable(root=sys.argv[1]))
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+jax.jit(lambda x: jnp.sin(x) * 3)(jnp.ones(8)).block_until_ready()
+"""
+
+
+@pytest.mark.parametrize("from_env", [True, False], ids=["env", "checkout"])
+def test_compile_cache_placement(tmp_path, from_env):
+    """JAX_COMPILATION_CACHE_DIR wins when set; otherwise the cache goes to
+    <root>/.jax_cache (the checkout by default), and nowhere else."""
+    env_dir, root = tmp_path / "from-env", tmp_path / "root"
+    env = dict(ENV)
+    if from_env:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(env_dir)
+    r = subprocess.run([sys.executable, "-c", CACHE_PROBE, str(root)],
+                       capture_output=True, text=True, timeout=300, env=env)
+    assert r.returncode == 0, r.stderr[-2000:]
+    want, other = ((env_dir, root / ".jax_cache") if from_env
+                   else (root / ".jax_cache", env_dir))
+    assert f"DIR {want}" in r.stdout
+    assert want.is_dir() and any(want.iterdir())
+    assert not other.exists()
+
+
+def test_compile_cache_default_is_the_checkout():
+    from repro.launch import compile_cache
+    assert os.path.isfile(os.path.join(compile_cache.CHECKOUT,
+                                       "chip_smoke.py"))
