@@ -1,0 +1,20 @@
+"""Published peaks of each chip, keyed by JAX's ``device_kind``.
+
+Source: Google Cloud documentation, "TPU v5e" (per chip: 197 TFLOP/s
+bf16, 819 GB/s of HBM bandwidth, 16 GB of HBM).  A device missing here is
+an error, never a default."""
+from __future__ import annotations
+
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bytes_per_s": 819e9,
+                    "hbm_bytes": 16e9},
+}
+
+
+def peaks(device_kind: str) -> dict:
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; add them to bench/peaks.py "
+                       f"with their source") from None
