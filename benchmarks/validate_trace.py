@@ -20,8 +20,6 @@ Checks (stdlib only, no jsonschema dependency):
     directory of them) is version 1, names a ``reason``, and carries a
     well-formed ring (``events``: entries with a known ``kind`` + name),
     an embedded metrics snapshot, and well-formed drift stats;
-  * a ``BENCH_history.json`` trajectory (``--history``) is a list of runs
-    each carrying a timestamp and the headline serve numbers;
   * a scheduler journal (``--journal``, JSONL from
     ``repro.serve.domains.SchedulerJournal``) has every line's sha256
     checksum recomputed and verified, every record kind known
@@ -33,7 +31,7 @@ Usage:
   python benchmarks/validate_trace.py --trace trace.json \
       [--metrics metrics.json] [--bench BENCH_serve.json] \
       [--strategy tuning_cache.json] [--flight flight-dumps/] \
-      [--history BENCH_history.json] [--journal journal.jsonl]
+      [--journal journal.jsonl]
 
 Exits non-zero with a message naming the first offending record, so a CI
 failure points at the event, not just the file.
@@ -264,25 +262,6 @@ def validate_flight(path: str) -> int:
     return len(paths)
 
 
-def validate_history(path: str) -> int:
-    with open(path) as f:
-        doc = json.load(f)
-    if not isinstance(doc, list):
-        fail(f"{path}: history must be a list of run entries")
-    for i, e in enumerate(doc):
-        w = f"{path}[{i}]"
-        if not isinstance(e, dict):
-            fail(f"{w}: not an object")
-        if not isinstance(e.get("t"), str) or not e["t"]:
-            fail(f"{w}: missing timestamp 't'")
-        if not isinstance(e.get("serve"), dict):
-            fail(f"{w}: missing 'serve' headline dict")
-        for field in ("recompiles", "drift"):
-            if not isinstance(e.get(field), (int, float)):
-                fail(f"{w}: missing numeric '{field}'")
-    return len(doc)
-
-
 # repro.serve.domains.JOURNAL_KINDS + the fields a replay needs per kind
 # (stdlib-only mirror: this validator must not import the repro tree)
 _JOURNAL_FIELDS = {
@@ -335,15 +314,13 @@ def main() -> None:
     ap.add_argument("--strategy", default=None)
     ap.add_argument("--flight", default=None,
                     help="flight-recorder dump file or directory of dumps")
-    ap.add_argument("--history", default=None,
-                    help="BENCH_history.json trajectory file")
     ap.add_argument("--journal", default=None,
                     help="scheduler journal (JSONL) to checksum-verify")
     args = ap.parse_args()
     if not (args.trace or args.metrics or args.bench or args.strategy
-            or args.flight or args.history or args.journal):
+            or args.flight or args.journal):
         fail("nothing to validate: pass --trace/--metrics/--bench/"
-             "--strategy/--flight/--history/--journal")
+             "--strategy/--flight/--journal")
     if args.trace:
         n = validate_trace(args.trace)
         print(f"validate_trace: {args.trace}: {n} events OK")
@@ -363,10 +340,6 @@ def main() -> None:
         n = validate_flight(args.flight)
         print(f"validate_trace: {args.flight}: {n} flight dump"
               f"{'s' if n != 1 else ''} OK")
-    if args.history:
-        n = validate_history(args.history)
-        print(f"validate_trace: {args.history}: {n} history entr"
-              f"{'ies' if n != 1 else 'y'} OK")
     if args.journal:
         n = validate_journal(args.journal)
         print(f"validate_trace: {args.journal}: {n} journal record"

@@ -462,25 +462,29 @@ class Model:
         else:
             def body(carry, layer):
                 x, ck, cv, li = carry
-                h_in = rmsnorm(x, layer.ln1, cfg.norm_eps)
-                y, ck, cv = attn_mod.paged_attention_prefill(
-                    layer.attn, cfg, h_in, ck, cv, li, bt_row, start,
-                    first=first)
+                with jax.named_scope("attention"):
+                    h_in = rmsnorm(x, layer.ln1, cfg.norm_eps)
+                    y, ck, cv = attn_mod.paged_attention_prefill(
+                        layer.attn, cfg, h_in, ck, cv, li, bt_row, start,
+                        first=first)
                 h = x + y
-                z = rmsnorm(h, layer.ln2, cfg.norm_eps)
-                if cfg.n_experts:
-                    out, _ = ffn_mod.moe(layer.mlp, cfg, z)
-                else:
-                    out = ffn_mod.mlp(layer.mlp, z)
+                with jax.named_scope("mlp"):
+                    z = rmsnorm(h, layer.ln2, cfg.norm_eps)
+                    if cfg.n_experts:
+                        out, _ = ffn_mod.moe(layer.mlp, cfg, z)
+                    else:
+                        out = ffn_mod.mlp(layer.mlp, z)
                 return (h + out, ck, cv, li + 1), None
             (x, ck, cv, _), _ = jax.lax.scan(
                 body, (x, kv.k, kv.v, jnp.int32(0)), params["blocks"])
             new_state = state
 
-        x = rmsnorm(x, params["ln_f"], cfg.norm_eps)
-        idx = jnp.clip(jnp.asarray(lengths, jnp.int32) - 1, 0, s - 1)
-        x_last = jnp.take_along_axis(x, idx[:, None, None], axis=1)[:, 0]
-        logits = jnp.einsum("bd,dv->bv", x_last, params["head"])
+        with jax.named_scope("head"):
+            x = rmsnorm(x, params["ln_f"], cfg.norm_eps)
+            idx = jnp.clip(jnp.asarray(lengths, jnp.int32) - 1, 0, s - 1)
+            x_last = jnp.take_along_axis(x, idx[:, None, None],
+                                         axis=1)[:, 0]
+            logits = jnp.einsum("bd,dv->bv", x_last, params["head"])
         return logits, KVCache(ck, cv), new_state
 
     def gather_paged_view(self, cache, block_tables):
@@ -573,24 +577,28 @@ class Model:
 
             def body(carry, layer):
                 (x, ck, cv, li), view = carry[:4], carry[4:]
-                h = rmsnorm(x, layer.ln1, cfg.norm_eps)
-                if kv_view is not None:
-                    y, ck, cv, vk, vv = attn_mod.paged_attention_decode_view(
-                        layer.attn, cfg, h, ck, cv, view[0], view[1], li,
-                        pos, block_tables)
-                    view = (vk, vv)
-                elif block_tables is not None:
-                    y, ck, cv = attn_mod.paged_attention_decode_inplace(
-                        layer.attn, cfg, h, ck, cv, li, pos, block_tables)
-                else:
-                    y, ck, cv = attn_mod.attention_decode_inplace(
-                        layer.attn, cfg, h, ck, cv, li, pos)
+                with jax.named_scope("attention"):
+                    h = rmsnorm(x, layer.ln1, cfg.norm_eps)
+                    if kv_view is not None:
+                        y, ck, cv, vk, vv = (
+                            attn_mod.paged_attention_decode_view(
+                                layer.attn, cfg, h, ck, cv, view[0],
+                                view[1], li, pos, block_tables))
+                        view = (vk, vv)
+                    elif block_tables is not None:
+                        y, ck, cv = attn_mod.paged_attention_decode_inplace(
+                            layer.attn, cfg, h, ck, cv, li, pos,
+                            block_tables)
+                    else:
+                        y, ck, cv = attn_mod.attention_decode_inplace(
+                            layer.attn, cfg, h, ck, cv, li, pos)
                 x = x + y
-                z = rmsnorm(x, layer.ln2, cfg.norm_eps)
-                if cfg.n_experts:
-                    out, _ = ffn_mod.moe(layer.mlp, cfg, z)
-                else:
-                    out = ffn_mod.mlp(layer.mlp, z)
+                with jax.named_scope("mlp"):
+                    z = rmsnorm(x, layer.ln2, cfg.norm_eps)
+                    if cfg.n_experts:
+                        out, _ = ffn_mod.moe(layer.mlp, cfg, z)
+                    else:
+                        out = ffn_mod.mlp(layer.mlp, z)
                 return (x + out, ck, cv, li + 1) + view, None
             out_carry, _ = jax.lax.scan(
                 body, (x, ck0, cv0, jnp.int32(0)) + tuple(kv0),
@@ -600,8 +608,9 @@ class Model:
             if kv_view is not None:
                 new_view = out_carry[4:6]
 
-        x = rmsnorm(x, params["ln_f"], cfg.norm_eps)
-        logits = jnp.einsum("bd,dv->bv", x[:, -1], params["head"])
+        with jax.named_scope("head"):
+            x = rmsnorm(x, params["ln_f"], cfg.norm_eps)
+            logits = jnp.einsum("bd,dv->bv", x[:, -1], params["head"])
         if kv_view is not None:
             return logits, new_cache, new_view
         return logits, new_cache

@@ -6,10 +6,12 @@ warm-up") are asserted by tests; this package makes them *observable* in
 any run:
 
   trace       span tracer (thread-local stacks, monotonic clocks,
-              near-zero overhead disabled) with Chrome/Perfetto JSON
-              export — ``obs.enable()``, ``with obs.span("name"): ...``,
-              ``obs.export_trace("trace.json")``, load in
-              https://ui.perfetto.dev
+              near-zero overhead off, a bounded buffer) that also
+              writes each span into a collecting JAX profiler session's
+              trace — ``obs.enable()``, ``with obs.span("name"): ...``,
+              ``obs.spans(t0_ns, t1_ns)``,
+              ``obs.export_trace("trace.json")`` (Chrome/Perfetto JSON,
+              load in https://ui.perfetto.dev)
   metrics     always-on process registry of counters / gauges /
               histograms (with interpolated p50/p95/p99 in every
               snapshot) — ``obs.counter("x").inc()``,
@@ -32,18 +34,23 @@ any run:
 
 The instrumented spine: ``Program.check/lower/compile`` spans, executor
 cache build/hit/AOT events, autotune enumeration + measurement spans,
-serving per-chunk spans, request-scoped lifecycle events (submit / admit
+serving boundary spans (every stretch of host time in
+``serve.step_chunk`` named, each blocking host sync a
+``serve.device_wait``), request-scoped lifecycle events (submit / admit
 / first_token / retire carry ``req_id``; decode chunks carry the
-co-batched ``req_ids``), per-request latency histograms (queue wait,
-TTFT, decode tok/s), KV pool occupancy gauges, and a recompile detector
-that flags jit-cache growth after engine warm-up.  ``Engine.stats()`` is
-the one-call summary.  See docs/observability.md.
+co-batched ``req_ids``) and lifecycle spans (queued / prefill / decode),
+per-request latency histograms (queue wait, TTFT, decode tok/s), KV pool
+occupancy gauges, and a recompile detector that flags jit-cache growth
+after engine warm-up.  ``Engine.stats()`` is the one-call summary.  See
+docs/observability.md.
 
 Tracing defaults off; enable programmatically or with ``REPRO_TRACE=1``
-(a path value also exports at exit).  Metrics, provenance, the recorder,
-and the audit are always on — they only run at boundaries (tuning,
-staging, chunk edges), never in a hot loop.  ``REPRO_FLIGHT_DIR`` makes
-the recorder write its dumps as ``flight-*.json`` artefacts.
+(a path value also exports at exit).  Spans also record, and land in
+the profiler's trace, whenever a JAX profiler session is collecting.
+Metrics, provenance, the recorder, and the audit are always on — they
+only run at boundaries (tuning, staging, chunk edges), never in a hot
+loop.  ``REPRO_FLIGHT_DIR`` makes the recorder write its dumps as
+``flight-*.json`` artefacts.
 """
 from __future__ import annotations
 
@@ -70,8 +77,8 @@ from .provenance import (  # noqa: F401
 from .provenance import clear as clear_decisions  # noqa: F401
 from .provenance import log as provenance_log  # noqa: F401
 from .trace import (  # noqa: F401
-    Tracer, disable, enable, enabled, instant, span, to_chrome, traced,
-    tracer,
+    Span, Tracer, complete, disable, enable, enabled, instant, recording,
+    span, spans, to_chrome, traced, tracer,
 )
 from .trace import clear as clear_trace  # noqa: F401
 from .trace import events as trace_events  # noqa: F401
@@ -83,9 +90,9 @@ from .recorder import emit as event  # noqa: F401, E402
 
 __all__ = [
     # tracing
-    "Tracer", "tracer", "enable", "disable", "enabled", "span", "traced",
-    "instant", "event", "trace_events", "clear_trace", "to_chrome",
-    "export_trace",
+    "Tracer", "Span", "tracer", "enable", "disable", "enabled", "recording",
+    "span", "traced", "instant", "complete", "spans", "event",
+    "trace_events", "clear_trace", "to_chrome", "export_trace",
     # metrics
     "MetricsRegistry", "registry", "counter", "gauge", "histogram",
     "metrics_snapshot", "metrics_reset", "export_metrics",

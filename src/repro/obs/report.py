@@ -12,7 +12,6 @@ Two modes:
       python -m repro.obs.report --metrics serve-metrics.json
       python -m repro.obs.report --flight flight-dumps/           # dir or file
       python -m repro.obs.report --trace serve-trace.json --request r3
-      python -m repro.obs.report --history BENCH_history.json
 
   ``--request`` stitches the per-request timeline out of a Chrome trace:
   every span/instant whose args carry that ``req_id`` (or list it in
@@ -31,7 +30,7 @@ import sys
 from typing import Dict, List, Optional
 
 __all__ = ["render", "render_metrics", "render_drift", "render_dump",
-           "render_history", "request_timeline", "main"]
+           "request_timeline", "main"]
 
 
 def _fmt(v: Optional[float]) -> str:
@@ -120,23 +119,6 @@ def render_dump(doc: dict) -> str:
     return "\n".join(lines)
 
 
-def render_history(entries: List[dict]) -> str:
-    """The committed BENCH_history.json trajectory, one line per run."""
-    if not entries:
-        return "bench history — empty"
-    lines = [f"bench history — {len(entries)} runs"]
-    for e in entries:
-        serve = e.get("serve") or {}
-        faults = (e.get("resilience") or {}).get("faults_injected", "-")
-        lines.append(
-            f"  {e.get('t', '?')}: "
-            f"fused={_fmt(serve.get('fused_tok_s'))} tok/s "
-            f"continuous={_fmt(serve.get('continuous_tok_s'))} tok/s "
-            f"recompiles={e.get('recompiles', '-')} "
-            f"drift={e.get('drift', '-')} faults={faults}")
-    return "\n".join(lines)
-
-
 def request_timeline(events: List[dict], req_id: str) -> str:
     """Stitch one request's timeline from Chrome trace events: everything
     whose args carry ``req_id`` or list it in ``req_ids``."""
@@ -203,7 +185,6 @@ def main(argv=None) -> int:
     p.add_argument("--trace", help="Chrome trace JSON (for --request)")
     p.add_argument("--request", help="render one request's timeline from "
                                      "--trace")
-    p.add_argument("--history", help="BENCH_history.json trajectory")
     p.add_argument("--live", action="store_true",
                    help="render the current process state")
     args = p.parse_args(argv)
@@ -228,8 +209,6 @@ def main(argv=None) -> int:
         doc = _load(args.trace)
         out.append(request_timeline(doc.get("traceEvents", []),
                                     args.request))
-    if args.history:
-        out.append(render_history(_load(args.history)))
     if args.live or not out:
         out.append(render())
     print("\n\n".join(out))

@@ -261,8 +261,9 @@ class _EngineBase:
                 # clean rows stay bitwise-identical with the guard on
                 bad = bad | ~jnp.isfinite(
                     logits.reshape(logits.shape[0], -1)).all(axis=1)
-                keys, sub = _split_keys(keys)
-                nxt = sample_tokens(logits, sub, temps, top_ks)
+                with jax.named_scope("sample"):
+                    keys, sub = _split_keys(keys)
+                    nxt = sample_tokens(logits, sub, temps, top_ks)
                 # clamp: a retired slot keeps decoding until the boundary;
                 # past max_seq its (per-slot-path) cache writes are dropped
                 # (the paged path drops through the block-table sentinel)
@@ -903,19 +904,28 @@ class ContinuousEngine(_EngineBase):
         """Admit pending requests, advance in-flight prompt prefills by one
         chunk each, then decode one fused chunk.
 
-        Returns the request ids retired at this boundary."""
-        try:
-            with obs.span("serve.step_chunk"):
+        Returns the request ids retired at this boundary.
+
+        While recording (``repro.obs``), ``serve.step_chunk`` spans the
+        whole call and its children name every stretch of host time:
+        ``serve.boundary_checks``, ``serve.admissions``,
+        ``serve.prefill_chunk`` (holding ``serve.finish_admit``),
+        ``serve.decode_chunk`` (the dispatch), ``serve.device_wait`` (each
+        blocking host sync, ``on`` naming which), ``serve.record`` and
+        ``serve.bookkeeping``."""
+        with obs.span("serve.step_chunk"):
+            try:
                 finished = self._step_chunk_inner()
-        except Exception as e:
-            # the resilience ladder is exhausted (or disabled) and the
-            # exception is about to leave the engine: capture the black box
-            obs.flight_dump("unhandled_exception",
-                            error=f"{type(e).__name__}: {e}")
-            raise
-        if self.journal is not None:
-            self._journal_sync(finished)
-        self._check_recompiles()
+            except Exception as e:
+                # the resilience ladder is exhausted (or disabled) and the
+                # exception is about to leave the engine: capture the box
+                obs.flight_dump("unhandled_exception",
+                                error=f"{type(e).__name__}: {e}")
+                raise
+            with obs.span("serve.bookkeeping"):
+                if self.journal is not None:
+                    self._journal_sync(finished)
+                self._check_recompiles()
         return finished
 
     def _journal_sync(self, finished: List[int]) -> None:
@@ -937,33 +947,43 @@ class ContinuousEngine(_EngineBase):
 
     def _step_chunk_inner(self) -> List[int]:
         finished: List[int] = []
-        # failure domains first: a lost host must be evacuated + the mesh
-        # shrunk before this boundary admits into (or decodes on) it
-        self._domain_sweep()
-        # deadline sweep next: an expired request must not consume the
-        # boundary's admission/prefill/decode work
-        for slot, rid in self.sched.check_deadlines():
-            if slot is not None:
-                self._evict_slot(slot)
-            finished.append(rid)
-        # pool integrity: a corrupt block pool means tables may alias pages
-        # across requests — degrade paged -> dense instead of decoding
-        # through a damaged mapping
-        if self.pool is not None and self.resilience.pool_check:
-            if faults.should_fire("serve.pool_corrupt") is not None:
-                faults.corrupt_pool(self.pool)
-            problems = self.pool.validate()
-            if problems:
-                finished.extend(
-                    self._degrade_to_dense("; ".join(problems)))
-        self.sched.admissions()               # reserve slots (and KV blocks)
-        if self.pool is not None:
-            obs.gauge("serve.kv_pool.used_blocks").set(self.pool.used_blocks)
-            obs.gauge("serve.kv_pool.free_blocks").set(self.pool.free_blocks)
+        with obs.span("serve.boundary_checks"):
+            # failure domains first: a lost host must be evacuated + the
+            # mesh shrunk before this boundary admits into (or decodes on)
+            self._domain_sweep()
+            # deadline sweep next: an expired request must not consume the
+            # boundary's admission/prefill/decode work
+            for slot, rid in self.sched.check_deadlines():
+                if slot is not None:
+                    self._evict_slot(slot)
+                finished.append(rid)
+            # pool integrity: a corrupt block pool means tables may alias
+            # pages across requests — degrade paged -> dense instead of
+            # decoding through a damaged mapping
+            if self.pool is not None and self.resilience.pool_check:
+                if faults.should_fire("serve.pool_corrupt") is not None:
+                    faults.corrupt_pool(self.pool)
+                problems = self.pool.validate()
+                if problems:
+                    finished.extend(
+                        self._degrade_to_dense("; ".join(problems)))
+        with obs.span("serve.admissions"):
+            self.sched.admissions()           # reserve slots (and KV blocks)
+            if self.pool is not None:
+                obs.gauge("serve.kv_pool.used_blocks").set(
+                    self.pool.used_blocks)
+                obs.gauge("serve.kv_pool.free_blocks").set(
+                    self.pool.free_blocks)
+                if obs.recording():
+                    # pages reserved against pages holding a written
+                    # position: the reservation the decode chunk runs with
+                    obs.instant("serve.kv_pages",
+                                reserved=self.pool.used_blocks,
+                                written=self.sched.written_blocks(),
+                                pool=self.pool.n_blocks)
         for slot, rid in self.sched.prefilling():
             if self._prefill_advance(slot, rid):      # one chunk per boundary
-                if self._finish_admit(slot, rid):
-                    finished.append(rid)
+                finished.append(rid)
         if self.sched.busy_slots():
             self._before_chunk()              # hook: ShardedEngine pins here
             req_ids = ",".join(str(s.req_id) for s in self.sched.slots
@@ -977,6 +997,7 @@ class ContinuousEngine(_EngineBase):
                         (self.params, self.cache, self.tokens, self.pos,
                          self.keys, self.temps, self.top_ks,
                          self.block_tables), req_ids=req_ids)
+                with obs.span("serve.device_wait", on="decode"):
                     block = np.asarray(toks)  # the chunk's one host sync
                     bad_host = np.asarray(bad)
             except Exception as e:
@@ -984,23 +1005,24 @@ class ContinuousEngine(_EngineBase):
                     raise
                 finished.extend(self._quarantine_chunk_failure(e))
             else:
-                # per-chunk wall time, measured at the boundary the host
-                # already pays: the latency histogram + the drift auditor's
-                # baseline-relative watch on this engine shape
-                dt = time.perf_counter() - t0
-                obs.histogram("serve.chunk_s").observe(dt)
-                obs.drift_observe(
-                    f"serve|decode_chunk|slots={self.slots}"
-                    f"|chunk={self.chunk}", dt)
-                slot_of = {s.req_id: i
-                           for i, s in enumerate(self.sched.slots)
-                           if not s.free}
-                if self.resilience.nan_guard and bad_host.any():
-                    finished.extend(self._quarantine_nan_rows(bad_host))
-                retired = self.sched.record_chunk(block)
-                for rid in retired:
-                    self._park_lane(slot_of[rid])
-                finished.extend(retired)
+                with obs.span("serve.record"):
+                    # per-chunk wall time, measured at the boundary the
+                    # host already pays: the latency histogram + the drift
+                    # auditor's baseline-relative watch on this shape
+                    dt = time.perf_counter() - t0
+                    obs.histogram("serve.chunk_s").observe(dt)
+                    obs.drift_observe(
+                        f"serve|decode_chunk|slots={self.slots}"
+                        f"|chunk={self.chunk}", dt)
+                    slot_of = {s.req_id: i
+                               for i, s in enumerate(self.sched.slots)
+                               if not s.free}
+                    if self.resilience.nan_guard and bad_host.any():
+                        finished.extend(self._quarantine_nan_rows(bad_host))
+                    retired = self.sched.record_chunk(block)
+                    for rid in retired:
+                        self._park_lane(slot_of[rid])
+                    finished.extend(retired)
         for rid in finished:                  # release prompts/keys at retire
             self._requests.pop(rid, None)
             self._stream_keys.pop(rid, None)
@@ -1179,8 +1201,9 @@ class ContinuousEngine(_EngineBase):
         self.pos = self.pos.at[slot].set(self.max_seq)
 
     def _prefill_advance(self, slot: int, rid: int) -> bool:
-        """Prefill the next prompt chunk of ``rid`` into ``slot``; True
-        when the whole prompt is in the cache.
+        """Prefill the next prompt chunk of ``rid`` into ``slot``; once the
+        whole prompt is in the cache, finish the admission (first token).
+        True when the request retired at once.
 
         Chunks are ``buckets[-1]`` tokens (the prefill-chunk cap); the tail
         is padded to the smallest bucket that fits, so the executable set
@@ -1188,14 +1211,17 @@ class ContinuousEngine(_EngineBase):
         r = self._requests[rid]
         plen = int(r.prompt.shape[0])
         start = self.sched.slots[slot].prefill_pos
-        if start == 0:
-            self._begin_admit(slot)
         take = min(plen - start, self.buckets[-1])
         bucket = pick_bucket(take, self.buckets)
         with obs.span("serve.prefill_chunk", slot=slot, req_id=rid,
                       bucket=bucket, start=start):
-            return self._prefill_advance_inner(slot, r, plen, start, take,
-                                               bucket)
+            if start == 0:
+                self._begin_admit(slot)
+            if not self._prefill_advance_inner(slot, r, plen, start, take,
+                                               bucket):
+                return False
+            with obs.span("serve.finish_admit", slot=slot, req_id=rid):
+                return self._finish_admit(slot, rid)
 
     def _prefill_advance_inner(self, slot, r, plen, start, take,
                                bucket) -> bool:
@@ -1258,8 +1284,12 @@ class ContinuousEngine(_EngineBase):
         length = int(r.prompt.shape[0])
         logits = self._admit_logits.pop(slot)
         staging = self._staging.pop(slot)
-        if (self.resilience.nan_guard
-                and not np.isfinite(np.asarray(logits)).all()):
+        finite = True
+        if self.resilience.nan_guard:
+            with obs.span("serve.device_wait", on="prefill_logits"):
+                logits_host = np.asarray(logits)
+            finite = np.isfinite(logits_host).all()
+        if not finite:
             # poisoned prompt: quarantine at admission, before the slot's
             # state ever joins the shared decode batch
             self._n_nan_quarantines += 1
@@ -1293,7 +1323,9 @@ class ContinuousEngine(_EngineBase):
         self.temps = self.temps.at[slot].set(temp[0])
         self.top_ks = self.top_ks.at[slot].set(top_k[0])
         # one tiny host sync per ADMISSION (not per token): the first token
-        done = self.sched.record_first(slot, int(np.asarray(first)[0]))
+        with obs.span("serve.device_wait", on="first_token"):
+            token = int(np.asarray(first)[0])
+        done = self.sched.record_first(slot, token)
         if done:
             self._park_lane(slot)
         elif faults.should_fire("serve.nan_decode", req_id=rid) is not None:

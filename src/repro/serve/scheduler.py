@@ -9,6 +9,11 @@ boundaries), and when a slot retires.  All decisions happen at chunk
 boundaries — inside a chunk the device runs a fused ``lax.scan`` with no
 host involvement, so the scheduler never sees (or blocks) individual tokens.
 
+While ``repro.obs`` records, each request leaves three spans that share
+its ``req_id`` and tile its life: ``serve.request.queued`` (submit to
+admission), ``serve.request.prefill`` (admission to first token sampled)
+and ``serve.request.decode`` (first token to retirement).
+
 Shape discipline: prompts are RIGHT-padded to a bucket from
 :func:`seq_buckets` and the decode batch is always exactly ``n_slots`` wide,
 so the jitted prefill/decode functions see a small closed set of shapes —
@@ -65,6 +70,11 @@ def pick_bucket(n: int, buckets: Sequence[int]) -> int:
         raise ValueError(f"prompt of {n} tokens exceeds the largest bucket "
                          f"{buckets[-1]}")
     return buckets[i]
+
+
+def _ns(t: float) -> int:
+    """A ``time.perf_counter`` stamp on the ``perf_counter_ns`` clock."""
+    return int(t * 1e9)
 
 
 @dataclasses.dataclass
@@ -195,6 +205,9 @@ class Scheduler:
             slot.prefill_len = meta["prompt_len"]
             self.n_admits += 1
             meta["t_admit"] = now
+            if obs.recording():
+                obs.complete("serve.request.queued",
+                             _ns(meta["t_submit"]), _ns(now), req_id=rid)
             obs.counter("serve.requests_admitted").inc()
             obs.histogram("serve.queue_wait_s").observe(
                 now - meta["t_submit"])
@@ -226,6 +239,9 @@ class Scheduler:
         meta = self.meta.get(slot.req_id)
         if meta is not None and "t_first" not in meta:
             meta["t_first"] = time.perf_counter()
+            if obs.recording() and "t_admit" in meta:
+                obs.complete("serve.request.prefill", _ns(meta["t_admit"]),
+                             _ns(meta["t_first"]), req_id=slot.req_id)
             ttft = meta["t_first"] - meta["t_submit"]
             obs.histogram("serve.ttft_s").observe(ttft)
             obs.event("serve.first_token", req_id=slot.req_id,
@@ -274,6 +290,9 @@ class Scheduler:
             if t_first is not None and n_tok > 1 and now > t_first:
                 obs.histogram("serve.decode_tok_s").observe(
                     (n_tok - 1) / (now - t_first))
+            if t_first is not None and obs.recording():
+                obs.complete("serve.request.decode", _ns(t_first), _ns(now),
+                             req_id=rid, state=state)
         obs.event("serve.retire", req_id=rid, slot=slot_idx, state=state)
         slot.req_id = -1
         slot.remaining = 0
@@ -420,6 +439,19 @@ class Scheduler:
         return list(self.pop_result(req_id).tokens)
 
     # -- state ---------------------------------------------------------------
+
+    def written_blocks(self) -> int:
+        """KV pages holding at least one written position, summed over
+        admitted slots: the prompt prefilled so far, plus every sampled
+        token but the newest (the next decode step writes that one).
+        Needs a block pool."""
+        bs = self.pool.block_size
+        n = 0
+        for s in self.slots:
+            if not s.free:
+                pos = s.prefill_pos + max(len(self.outputs[s.req_id]) - 1, 0)
+                n += -(-pos // bs)
+        return n
 
     def busy_slots(self) -> List[int]:
         """Slots actively DECODING (admitted and fully prefilled)."""

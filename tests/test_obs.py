@@ -701,13 +701,6 @@ class TestReport:
                "drift": {}}
         out = report.render_dump(doc)
         assert "request_failed" in out and "serve.decode_chunk" in out
-        hist = [{"t": "2026-08-08T00:00:00Z",
-                 "serve": {"fused_tok_s": 5000.0},
-                 "recompiles": 0, "drift": 0,
-                 "resilience": {"faults_injected": 10}}]
-        out = report.render_history(hist)
-        assert "fused=5000" in out
-        assert "empty" in report.render_history([])
 
     def test_live_render_smoke(self):
         from repro.obs import report
@@ -723,11 +716,7 @@ class TestReport:
         obs.configure_flight(dir=str(tmp_path / "fl"))
         obs.flight_dump("unit_cli", req_id=9)
         obs.configure_flight(dir=None)
-        hist = tmp_path / "hist.json"
-        hist.write_text(json.dumps([{"t": "2026-08-08", "serve": {},
-                                     "recompiles": 0, "drift": 0}]))
-        rc = report.main(["--flight", str(tmp_path / "fl"),
-                          "--history", str(hist)])
+        rc = report.main(["--flight", str(tmp_path / "fl")])
         assert rc == 0
         out = capsys.readouterr().out
-        assert "unit_cli" in out and "bench history" in out
+        assert "unit_cli" in out
