@@ -12,7 +12,10 @@ must be within ``LOGIT_RTOL`` of the top logit at its position.  The dense
 where the two engines part, the top-2 logit margin there is printed.
 Then the six ``dpia-pallas`` ops and the hand-written matmul, rmsnorm and
 flash-attention kernels run compiled for the chip (``interpret=False``)
-against ``repro.kernels.ref``.
+against ``repro.kernels.ref``, and the paged decode kernel against the jnp
+attention over the gathered view it replaces, at the page pools of the
+benchmark's two cells; a decode step's attention over every layer is then
+timed at the kernel's block of pages and at twice and four times it.
 
 ``--chips 4``: ``ShardedEngine`` over a ``data=4`` mesh serves the same
 traffic greedily, with its decode state on all four chips, next to
@@ -58,6 +61,18 @@ OP_SIZES = {"n": 1 << 22, "mm": (1024, 2560, 9728), "rows": 4096, "d": 2560}
 # hand-written kernels at qwen3-4b serving shapes (a 1024-token prefill)
 KERNEL_SIZES = {"tokens": 1024, "d": 2560, "ff": 9728, "heads": 32,
                 "kv_heads": 8, "head_dim": 128}
+# paged decode at the pools of the benchmark's cells (bench/configs): 8
+# slots, 32 query heads of 128, 16-position pages; qwen3-4b's 36 layers of
+# 528 pages and 8 kv heads over 2048 positions, yi-9b-l24's 24 of 2048 and
+# 4 over 4096
+PAGED_SIZES = ({"name": "qwen3-4b", "layers": 36, "pages": 528,
+                "kv_heads": 8, "max_seq": 2048},
+               {"name": "yi-9b-l24", "layers": 24, "pages": 2048,
+                "kv_heads": 4, "max_seq": 4096})
+PAGED_SLOTS, PAGED_HEADS, PAGED_HEAD_DIM, PAGED_BLOCK = 8, 32, 128, 16
+# a decode step's slot lengths, as shares of max_seq: six busy slots at
+# 22-40% of it, two parked
+STEP_LEN_SHARES = (0.28, 0.34, 0.22, 0.4, 0.3, 0.34, 0.0, 0.0)
 
 
 def fail(msg: str) -> None:
@@ -271,8 +286,85 @@ def phase_serve(clock: CompileClock, *, smoke: bool = False,
     check_kernel_counters("serve")
 
 
+def paged_decode_check(shape: dict, close, interpret: bool) -> None:
+    """The paged decode kernel against the jnp decode attention over the
+    gathered view (``models.attention._attend_token``'s math) at one pool:
+    lengths 1, 15, 16, 17, mid and full, a sentinel-padded tail and a
+    parked lane, at two layers other than 0; then a decode step's
+    attention over every layer at ``STEP_LEN_SHARES``, timed at the
+    kernel's pages per block and at two and four times as many."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.kernels import paged_decode
+    from repro.models.attention import gather_paged_view
+
+    L, nb, nkv = shape["layers"], shape["pages"], shape["kv_heads"]
+    b, nh, hd, bs = PAGED_SLOTS, PAGED_HEADS, PAGED_HEAD_DIM, PAGED_BLOCK
+    mb = shape["max_seq"] // bs
+    ks = jax.random.split(jax.random.PRNGKey(SEED + 1), 4)
+    pool = (L, nb, bs, nkv, hd)
+    kp = jax.random.normal(ks[0], pool, jnp.bfloat16)
+    vp = jax.random.normal(ks[1], pool, jnp.bfloat16)
+    q = jax.random.normal(ks[2], (b, nh, hd), jnp.bfloat16)
+    qg = q.reshape(b, nkv, nh // nkv, hd)
+    tail = mb * bs // 4 + 3
+    bt = jax.random.randint(ks[3], (b, mb), 0, nb, jnp.int32)
+    bt = bt.at[6, -(-tail // bs):].set(nb).at[7].set(nb)
+    lengths = jnp.asarray([1, 15, 16, 17, mb * bs // 2 + 5, mb * bs, tail,
+                           1], jnp.int32)
+
+    def view_attention(layer):
+        vk, vv = gather_paged_view(kp[layer][None], vp[layer][None], bt)
+        s = jnp.einsum("bngh,btnh->bngt", qg, vk[0],
+                       preferred_element_type=jnp.float32) / hd ** 0.5
+        valid = jnp.arange(mb * bs) < lengths[:, None, None, None]
+        p = jax.nn.softmax(jnp.where(valid, s, -jnp.inf), axis=-1)
+        o = jnp.einsum("bngt,btnh->bngh", p.astype(vv.dtype), vv[0],
+                       preferred_element_type=jnp.float32)
+        return o.reshape(b, nh, hd)
+
+    for layer in (1, L - 1):
+        close(f"pallas paged_decode_attention ({shape['name']}, layer "
+              f"{layer})",
+              paged_decode.paged_decode_attention(q, kp, vp, jnp.int32(layer),
+                                                  lengths, bt),
+              lambda: view_attention(layer), 2e-2)
+
+    lens = jnp.asarray([max(1, round(f * shape["max_seq"]))
+                        for f in STEP_LEN_SHARES], jnp.int32)
+    valid_bytes = int(lens.sum()) * L * 2 * nkv * hd * kp.dtype.itemsize
+    reps = 1 if interpret else 20
+    ppb0 = paged_decode._pages_per_block(bs)
+    for ppb in (ppb0, 2 * ppb0, 4 * ppb0):
+        call = functools.partial(paged_decode._call, ppb=ppb,
+                                 interpret=interpret)
+
+        @jax.jit
+        def step(qg, kp, vp, lens, bt):
+            def layer(c, li):
+                return c + call(qg, kp, vp, li, lens, bt).sum(), None
+            return jax.lax.scan(layer, jnp.float32(0), jnp.arange(L))[0]
+
+        out = step(qg, kp, vp, lens, bt)
+        if not np.isfinite(float(out)):
+            fail(f"paged decode step at {ppb} pages per block: non-finite")
+        t = time.perf_counter()
+        for _ in range(reps):
+            out = step(qg, kp, vp, lens, bt)
+        jax.block_until_ready(out)
+        ms = (time.perf_counter() - t) / reps * 1e3
+        log(f"pallas paged_decode_attention ({shape['name']}): a decode "
+            f"step over {L} layers at lengths {[int(n) for n in lens]}, "
+            f"{ppb} pages per block: {ms:.3f} ms, "
+            f"{valid_bytes / ms / 1e6:.1f} GB/s of {valid_bytes} valid KV "
+            f"bytes")
+
+
 def phase_kernels(clock: CompileClock, sizes=OP_SIZES,
-                  ksizes=KERNEL_SIZES, interpret: bool = False) -> list:
+                  ksizes=KERNEL_SIZES, psizes=PAGED_SIZES,
+                  interpret: bool = False) -> list:
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -342,7 +434,11 @@ def phase_kernels(clock: CompileClock, sizes=OP_SIZES,
     close("pallas flash_attention (bf16, causal)",
           flash_attention(q, kv[0], kv[1], interpret=interpret),
           lambda: ref.flash_attention(f32(q), f32(kv[0]), f32(kv[1])), 2e-2)
-    ran += ["pallas matmul", "pallas rmsnorm", "pallas flash_attention"]
+    for shape in psizes:
+        paged_decode_check(shape, close, interpret)
+        gc.collect()
+    ran += ["pallas matmul", "pallas rmsnorm", "pallas flash_attention",
+            "pallas paged_decode_attention"]
     check_kernel_counters("pallas kernels")
     clock.phase("kernels", c0, t0)
     return ran

@@ -12,7 +12,7 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import ops
+from repro.kernels import ops, paged_decode
 from .common import ModelConfig, apply_rope, init_dense, rmsnorm, rope_freqs
 
 
@@ -377,6 +377,24 @@ def paged_attention_prefill(p: AttnParams, cfg: ModelConfig, x, ck, cv, li,
     return jnp.einsum("bsh,hd->bsd", out, p.wo), ck, cv
 
 
+def _paged_write_token(p: AttnParams, cfg: ModelConfig, x, ck, cv, li, pos,
+                       bt):
+    """Project one token per slot and scatter its k/v into layer ``li`` of
+    the page pools through the block table (dropping on a sentinel or
+    overflow entry: a lane parked at ``max_seq`` writes nothing).
+    Returns ``(pos, q, k, v, ck, cv)`` with ``pos`` broadcast to (b,)."""
+    b = x.shape[0]
+    nb, bs = ck.shape[1], ck.shape[2]
+    pos = jnp.asarray(pos, jnp.int32)
+    if pos.ndim == 0:
+        pos = jnp.broadcast_to(pos, (b,))
+    q, k, v = _project_qkv(p, cfg, x, pos[:, None])
+    page, off = _page_slots(pos, bt, bs, nb)
+    ck = ck.at[li, page, off].set(k[:, 0].astype(ck.dtype), mode="drop")
+    cv = cv.at[li, page, off].set(v[:, 0].astype(cv.dtype), mode="drop")
+    return pos, q, k, v, ck, cv
+
+
 def paged_attention_decode_inplace(p: AttnParams, cfg: ModelConfig, x, ck,
                                    cv, li, pos, bt):
     """One-token decode against layer-stacked page pools — the paged twin
@@ -391,15 +409,7 @@ def paged_attention_decode_inplace(p: AttnParams, cfg: ModelConfig, x, ck,
     masked-attention math as the dense path, so paged decode is bitwise
     the dense computation whenever ``max_blocks * block_size == max_seq``.
     """
-    b = x.shape[0]
-    nb, bs = ck.shape[1], ck.shape[2]
-    pos = jnp.asarray(pos, jnp.int32)
-    if pos.ndim == 0:
-        pos = jnp.broadcast_to(pos, (b,))
-    q, k, v = _project_qkv(p, cfg, x, pos[:, None])
-    page, off = _page_slots(pos, bt, bs, nb)
-    ck = ck.at[li, page, off].set(k[:, 0].astype(ck.dtype), mode="drop")
-    cv = cv.at[li, page, off].set(v[:, 0].astype(cv.dtype), mode="drop")
+    pos, q, _, _, ck, cv = _paged_write_token(p, cfg, x, ck, cv, li, pos, bt)
     k_l = jax.lax.dynamic_index_in_dim(ck, li, axis=0, keepdims=False)
     v_l = jax.lax.dynamic_index_in_dim(cv, li, axis=0, keepdims=False)
     k_all = _gather_pages(k_l, bt)
@@ -436,22 +446,53 @@ def paged_attention_decode_view(p: AttnParams, cfg: ModelConfig, x, ck, cv,
     page pool (ck, cv) through the block table, so the pool stays the
     source of truth across chunk boundaries.  Writes drop both ways for a
     parked lane (pos past the view / sentinel page)."""
-    b = x.shape[0]
-    nb, bs = ck.shape[1], ck.shape[2]
-    pos = jnp.asarray(pos, jnp.int32)
-    if pos.ndim == 0:
-        pos = jnp.broadcast_to(pos, (b,))
-    q, k, v = _project_qkv(p, cfg, x, pos[:, None])
-    slots = jnp.arange(b)
+    pos, q, k, v, ck, cv = _paged_write_token(p, cfg, x, ck, cv, li, pos, bt)
+    slots = jnp.arange(x.shape[0])
     vk = vk.at[li, slots, pos].set(k[:, 0].astype(vk.dtype), mode="drop")
     vv = vv.at[li, slots, pos].set(v[:, 0].astype(vv.dtype), mode="drop")
-    page, off = _page_slots(pos, bt, bs, nb)
-    ck = ck.at[li, page, off].set(k[:, 0].astype(ck.dtype), mode="drop")
-    cv = cv.at[li, page, off].set(v[:, 0].astype(cv.dtype), mode="drop")
     k_l = jax.lax.dynamic_index_in_dim(vk, li, axis=0, keepdims=False)
     v_l = jax.lax.dynamic_index_in_dim(vv, li, axis=0, keepdims=False)
     return _attend_token(cfg, q, k_l, v_l, pos, True, x.dtype,
                          p.wo), ck, cv, vk, vv
+
+
+def paged_kernel_engages(pool) -> bool:
+    """Whether decode attention over the layer-stacked page ``pool`` runs
+    the Pallas paged kernel (:func:`paged_attention_decode_kernel`): the
+    pool is an array (or a shape with a sharding) on exactly one TPU
+    device, in a layout the kernel reads.  Elsewhere — the CPU, or a pool
+    sharded over a mesh, where a ``pallas_call`` is not partitioned — the
+    decode chunk gathers the per-slot view (:func:`gather_paged_view`)."""
+    sharding = getattr(pool, "sharding", None)     # None on a tracer
+    if sharding is None:
+        return False
+    devices = sharding.device_set
+    return (len(devices) == 1 and next(iter(devices)).platform == "tpu"
+            and paged_decode.supports(pool.shape, pool.dtype))
+
+
+def paged_attention_decode_kernel(p: AttnParams, cfg: ModelConfig, x, ck,
+                                  cv, li, pos, bt):
+    """One-token decode against layer-stacked page pools through the Pallas
+    paged kernel: each slot reads only its valid pages, straight from the
+    pool, and no view is gathered.
+
+    ck/cv: (L, n_blocks, block_size, nkv, hd); pos: (b,) per-slot
+    positions; bt: (b, max_blocks).  The new token is scattered into the
+    pool through the table as :func:`paged_attention_decode_view` does
+    (dropping for a parked lane), then slot ``i`` attends positions
+    ``[0, pos[i]]``.  A parked lane (position at the table's capacity:
+    free, retired or mid-prefill) attends one page; its logits are
+    discarded by the engine but stay finite.  The same math as
+    :func:`_attend_token` (float32 scores and softmax, PV in the pool's
+    dtype), accumulated blockwise, so it matches the view path to bf16
+    rounding, not bitwise."""
+    pos, q, _, _, ck, cv = _paged_write_token(p, cfg, x, ck, cv, li, pos, bt)
+    lengths = jnp.where(pos < bt.shape[1] * ck.shape[2], pos + 1, 1)
+    out = paged_decode.paged_decode_attention(
+        q[:, 0].astype(ck.dtype), ck, cv, li, lengths, bt)
+    out = out.astype(x.dtype).reshape(x.shape[0], 1, -1)
+    return jnp.einsum("bsh,hd->bsd", out, p.wo), ck, cv
 
 
 def attention_decode(p: AttnParams, cfg: ModelConfig, x, cache: KVCache,

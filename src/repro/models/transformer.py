@@ -497,7 +497,7 @@ class Model:
         return attn_mod.gather_paged_view(kv.k, kv.v, block_tables)
 
     def decode_step(self, params, token, cache, pos, block_tables=None,
-                    kv_view=None):
+                    kv_view=None, paged_kernel: bool = False):
         """token: (b, 1[, K]) -> (logits (b, vocab), new cache).
 
         ``pos`` is a scalar (lock-step batch) or a (b,) per-slot position
@@ -512,7 +512,12 @@ class Model:
         (vk, vv) pair from :meth:`gather_paged_view`, gathered once per
         chunk) attention runs against the view and the return value is
         ``(logits, cache, view)`` — the fused chunk's amortised-gather
-        form."""
+        form.  With ``paged_kernel`` (static; the pools live on one TPU,
+        :func:`repro.models.attention.paged_kernel_engages`) attention
+        reads each slot's valid pages straight from the pool through the
+        Pallas kernel
+        (:func:`repro.models.attention.paged_attention_decode_kernel`)
+        and no view is gathered."""
         cfg = self.cfg
         x = self.embed(params, token)
         b = x.shape[0]
@@ -553,6 +558,10 @@ class Model:
                         hp.shared_attn, cfg, xa, ck, cv, view[0], view[1],
                         gi, pos, block_tables)
                     view = (vk, vv)
+                elif block_tables is not None and paged_kernel:
+                    y, ck, cv = attn_mod.paged_attention_decode_kernel(
+                        hp.shared_attn, cfg, xa, ck, cv, gi, pos,
+                        block_tables)
                 elif block_tables is not None:
                     y, ck, cv = attn_mod.paged_attention_decode_inplace(
                         hp.shared_attn, cfg, xa, ck, cv, gi, pos,
@@ -585,6 +594,10 @@ class Model:
                                 layer.attn, cfg, h, ck, cv, view[0],
                                 view[1], li, pos, block_tables))
                         view = (vk, vv)
+                    elif block_tables is not None and paged_kernel:
+                        y, ck, cv = attn_mod.paged_attention_decode_kernel(
+                            layer.attn, cfg, h, ck, cv, li, pos,
+                            block_tables)
                     elif block_tables is not None:
                         y, ck, cv = attn_mod.paged_attention_decode_inplace(
                             layer.attn, cfg, h, ck, cv, li, pos,

@@ -67,6 +67,7 @@ import numpy as np
 
 from repro import obs
 from repro.ft.resilience import Watchdog
+from repro.models import attention as attn_mod
 from repro.models.transformer import Model
 from repro.serve.resilience import (RequestResult, ResilienceConfig,
                                     record_degradation)
@@ -170,6 +171,46 @@ class Request:
     ttft_deadline_s: Optional[float] = None
 
 
+def decode_attention(model: Model, cache, block_tables) -> str:
+    """How the decode chunk attends: ``"paged_kernel"`` where the cache's
+    KV pool lives on one TPU device
+    (:func:`repro.models.attention.paged_kernel_engages`), ``"view"`` for
+    other paged pools (the chunk gathers the per-slot view), ``"dense"``
+    without block tables or KV pools."""
+    kv = None if block_tables is None else model.split_paged_cache(cache)[0]
+    if kv is None:
+        return "dense"
+    return ("paged_kernel" if attn_mod.paged_kernel_engages(kv.k)
+            else "view")
+
+
+class _DecodeChunk:
+    """The jitted decode chunk, one program per attention path.  A call
+    takes the path as the static ``paged_kernel`` (False: the view or dense
+    program), chosen by its caller once per chunk; ``lower`` chooses it
+    from the arguments' placement (:func:`decode_attention`), so a compile
+    for a described chip gets the program the chip would run."""
+
+    def __init__(self, fn, model: Model):
+        self.__wrapped__ = fn
+        self._model = model
+        # cache + token/pos/key buffers are donated: decode is copy-free
+        # and the engine rebinds the returned buffers each chunk.  ``bt``
+        # (the block tables; None for dense layouts) is tiny and read-only
+        self._jit = jax.jit(fn, donate_argnums=(1, 2, 3, 4),
+                            static_argnames=("paged_kernel",))
+
+    def __call__(self, *args, paged_kernel: bool = False):
+        return self._jit(*args, paged_kernel=paged_kernel)
+
+    def lower(self, *args):
+        attn = decode_attention(self._model, args[1], args[7])
+        return self._jit.lower(*args, paged_kernel=attn == "paged_kernel")
+
+    def _cache_size(self) -> int:
+        return self._jit._cache_size()
+
+
 # ---------------------------------------------------------------------------
 # shared engine core
 # ---------------------------------------------------------------------------
@@ -234,12 +275,15 @@ class _EngineBase:
     def _make_chunk_fn(self):
         model, cfg, max_seq = self.model, self.model.cfg, self.max_seq
 
-        def chunk_fn(params, cache, tokens, pos, keys, temps, top_ks, bt):
-            # paged: gather each slot's pages into a dense-shaped view ONCE
-            # per chunk; steps attend/update the view and mirror the token
-            # write into the pool — the page indirection is paid per chunk,
-            # not per token per layer
-            view = None if bt is None else model.gather_paged_view(cache, bt)
+        def chunk_fn(params, cache, tokens, pos, keys, temps, top_ks, bt,
+                     paged_kernel=False):
+            # paged on one TPU (``paged_kernel``): each step's attention
+            # reads only each slot's valid pages, straight from the pool.
+            # Other paged pools gather each slot's pages into a
+            # dense-shaped view ONCE per chunk; steps attend/update the
+            # view and mirror the token write into the pool
+            view = (None if bt is None or paged_kernel
+                    else model.gather_paged_view(cache, bt))
             bad0 = jnp.zeros(tokens.shape[:1], bool)
 
             def step(carry, _):
@@ -250,8 +294,9 @@ class _EngineBase:
                         tok[..., None],
                         (tok.shape[0], 1, cfg.n_codebooks))
                 if view is None:
-                    logits, cache = model.decode_step(params, tok, cache,
-                                                      pos, block_tables=bt)
+                    logits, cache = model.decode_step(
+                        params, tok, cache, pos, block_tables=bt,
+                        paged_kernel=paged_kernel)
                 else:
                     logits, cache, view = model.decode_step(
                         params, tok, cache, pos, block_tables=bt,
@@ -275,10 +320,7 @@ class _EngineBase:
                 length=self.chunk)
             return cache, tokens, pos, keys, toks.T, bad  # toks: (b, chunk)
 
-        # cache + token/pos/key buffers are donated: decode is copy-free and
-        # the engine rebinds the returned buffers each chunk.  ``bt`` (the
-        # block tables; None for dense layouts) is tiny and read-only.
-        return jax.jit(chunk_fn, donate_argnums=(1, 2, 3, 4))
+        return _DecodeChunk(chunk_fn, model)
 
     def _on_straggler(self, chunk_i: int, dt: float) -> None:
         obs.counter("serve.stragglers").inc()
@@ -297,8 +339,10 @@ class _EngineBase:
                 return True
         return False
 
-    def _call_chunk(self, args, req_ids: str = ""):
-        """Invoke the fused decode chunk with the resilience wrapping: the
+    def _call_chunk(self, args, req_ids: str = "",
+                    paged_kernel: bool = False):
+        """Invoke the fused decode chunk (on the Pallas paged-kernel path
+        where ``paged_kernel``) with the resilience wrapping: the
         ``serve.slow_chunk`` / ``serve.chunk_error`` fault sites, the
         chunk-level straggler watchdog, and bounded retry-with-backoff for
         transient failures.
@@ -325,6 +369,8 @@ class _EngineBase:
                     if f is not None:
                         time.sleep(float(f.value or 0.05))
                     faults.raise_if("serve.chunk_error", req_ids=req_ids)
+                    if paged_kernel:   # else the plain view/dense call
+                        return self._chunk_fn(*args, paged_kernel=True)
                     return self._chunk_fn(*args)
                 finally:
                     if self._watchdog is not None:
@@ -988,15 +1034,20 @@ class ContinuousEngine(_EngineBase):
             self._before_chunk()              # hook: ShardedEngine pins here
             req_ids = ",".join(str(s.req_id) for s in self.sched.slots
                                if not s.free)
+            attn = decode_attention(self.model, self.cache,
+                                    self.block_tables)
             t0 = time.perf_counter()
             try:
                 with obs.span("serve.decode_chunk", chunk=self.chunk,
-                              req_ids=req_ids):
+                              req_ids=req_ids, attn=attn):
                     (self.cache, self.tokens, self.pos, self.keys, toks,
                      bad) = self._call_chunk(
                         (self.params, self.cache, self.tokens, self.pos,
                          self.keys, self.temps, self.top_ks,
-                         self.block_tables), req_ids=req_ids)
+                         self.block_tables), req_ids=req_ids,
+                        paged_kernel=attn == "paged_kernel")
+                    if attn == "paged_kernel":
+                        obs.counter("serve.decode_attn.paged_kernel").inc()
                 with obs.span("serve.device_wait", on="decode"):
                     block = np.asarray(toks)  # the chunk's one host sync
                     bad_host = np.asarray(bad)

@@ -18,6 +18,8 @@ LENS = (8, 12, 20, 33, 40)
 OPS = {"n": 8192, "mm": (128, 256, 384), "rows": 64, "d": 256}
 KERNELS = {"tokens": 128, "d": 256, "ff": 512, "heads": 4, "kv_heads": 2,
            "head_dim": 128}
+PAGED = ({"name": "gqa4", "layers": 3, "pages": 24, "kv_heads": 8,
+          "max_seq": 160},)
 
 
 def _chip_smoke():
@@ -34,11 +36,12 @@ def test_one_chip_phases(capsys):
     clock = smoke.CompileClock()
     smoke.phase_serve(clock, smoke=True, lens=LENS)
     ran = smoke.phase_kernels(clock, sizes=OPS, ksizes=KERNELS,
-                              interpret=True)
+                              psizes=PAGED, interpret=True)
     out = capsys.readouterr().out
     assert ran == ["dot", "asum", "scal", "matmul", "rmsnorm", "softmax",
                    "pallas matmul", "pallas rmsnorm",
-                   "pallas flash_attention"]
+                   "pallas flash_attention",
+                   "pallas paged_decode_attention"]
     n = len(LENS) * smoke.MAX_NEW
     assert f"ContinuousEngine(paged): teacher-forced through Model.forward, " \
            f"{n} of {n} tokens are the forward argmax" in out

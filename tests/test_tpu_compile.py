@@ -6,19 +6,24 @@ So the kernels of the serving path, the DPIA->Pallas translation of the
 paper's ops and one qwen3-4b decode step are compiled at real widths with
 ``interpret=False``.  The topology is described inside a fixture, never at
 import, so test workers that do not run this file never load libtpu."""
+import dataclasses
 import os
+import types
 
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
 from repro import compiler
 from repro.configs import config
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.matmul import matmul
+from repro.kernels.paged_decode import paged_decode_attention
 from repro.kernels.rmsnorm import rmsnorm
+from repro.models import attention
 from repro.models.transformer import Model
+from repro.serve.engine import ContinuousEngine
 
 V5E_HBM_BYTES = 16e9
 N = 1 << 22           # a 16 MiB float32 operand: too big to sit whole in VMEM
@@ -127,3 +132,65 @@ def test_qwen3_4b_decode_step_fits_v5e(one_chip):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert used < V5E_HBM_BYTES, f"decode step needs {used} bytes of HBM"
+
+
+# the cells' pools: qwen3-4b (36 layers, 528 pages, 8 kv heads) and
+# yi-9b-l24 (24 layers, 2048 pages, 4 kv heads), 16-position pages
+_POOLS = [("qwen3-4b", 36, 528, 8, 2048), ("yi-9b-l24", 24, 2048, 4, 4096)]
+
+
+@pytest.mark.parametrize("layers,n_blocks,nkv,max_seq",
+                         [p[1:] for p in _POOLS], ids=[p[0] for p in _POOLS])
+def test_paged_decode_kernel_compiles(one_chip, layers, n_blocks, nkv,
+                                      max_seq):
+    bf, i32 = jnp.bfloat16, jnp.int32
+    pool = _sds(one_chip, (layers, n_blocks, 16, nkv, HEAD_DIM), bf)
+    text = _kernel_hlo(
+        lambda q, k, v, li, n, bt: paged_decode_attention(q, k, v, li, n, bt),
+        _sds(one_chip, (8, HEADS, HEAD_DIM), bf), pool, pool,
+        _sds(one_chip, (), i32), _sds(one_chip, (8,), i32),
+        _sds(one_chip, (8, max_seq // 16), i32))
+    # the pools go to the kernel as they are: no copy of a pool
+    assert "copy(%k" not in text and "copy(%v" not in text
+
+
+def test_paged_kernel_engages_only_for_a_pool_on_one_tpu(topo, one_chip):
+    from repro.launch.mesh import make_mesh
+    shape = (36, 528, 16, KV_HEADS, HEAD_DIM)
+    assert attention.paged_kernel_engages(_sds(one_chip, shape, jnp.bfloat16))
+    # the CPU, a shape with no placement, a pool sharded over a mesh
+    assert not attention.paged_kernel_engages(jnp.zeros((1, 2, 16, 2, 128)))
+    assert not attention.paged_kernel_engages(
+        jax.ShapeDtypeStruct(shape, jnp.bfloat16))
+    mesh = make_mesh((4,), ("data",), devices=topo.devices)
+    assert not attention.paged_kernel_engages(
+        _sds(NamedSharding(mesh, PartitionSpec()), shape, jnp.bfloat16))
+    # one TPU, but a layout the kernel does not read
+    assert not attention.paged_kernel_engages(
+        _sds(one_chip, (36, 528, 16, 3, HEAD_DIM), jnp.bfloat16))
+    assert not attention.paged_kernel_engages(
+        _sds(one_chip, (36, 528, 16, KV_HEADS, 64), jnp.bfloat16))
+
+
+def test_decode_chunk_runs_the_paged_kernel_for_v5e(one_chip):
+    """Two layers at qwen3-4b's widths: the paged decode chunk compiled
+    for a described v5e runs the Mosaic kernel and gathers no view."""
+    model = Model(dataclasses.replace(config("qwen3_4b"), n_layers=2))
+
+    def place(tree):
+        return jax.tree_util.tree_map(
+            lambda x: _sds(one_chip, x.shape, x.dtype), tree)
+
+    params = place(jax.eval_shape(model.init_params, jax.random.PRNGKey(0)))
+    cache = place(jax.eval_shape(lambda: model.init_paged_cache(
+        8, 2048, n_blocks=528, block_size=16)))
+    i32 = lambda *s: _sds(one_chip, s, jnp.int32)  # noqa: E731
+    chunk = ContinuousEngine._make_chunk_fn(types.SimpleNamespace(
+        model=model, max_seq=2048, chunk=8))
+    text = chunk.lower(params, cache, i32(8), i32(8),
+                       _sds(one_chip, (8, 2), jnp.uint32),
+                       _sds(one_chip, (8,)), i32(8),
+                       i32(8, 2048 // 16)).compile().as_text()
+    assert "tpu_custom_call" in text
+    # no (layers, slots, max_seq, kv heads, head_dim) view in the program
+    assert "bf16[2,8,2048,8,128]" not in text
