@@ -2,10 +2,11 @@
 
 Once the window has closed, a sample of the requests the engine finished,
 drawn from the seed and always holding the one with the most served
-tokens, goes through the float32 reference: each prompt with its served
-tokens, teacher-forced.  At every served token the reference's best logit
-minus the logit of the token the engine served is that token's gap; the
-number compared is the widest gap.  Greedy serving of the reference's own
+tokens, goes through the float32 reference of the configuration's
+architecture (``bench/archs/``): each prompt with its served tokens,
+teacher-forced.  At every served token the reference's best logit minus
+the logit of the token the engine served is that token's gap; the number
+compared is the widest gap.  Greedy serving of the reference's own
 model would read 0, up to near-ties that rounding may flip.
 """
 from __future__ import annotations
@@ -14,7 +15,7 @@ from typing import List, Tuple
 
 import numpy as np
 
-from bench import reference
+from bench import reference, spec
 
 
 def sample(finished: List[Tuple[np.ndarray, list]], k: int, seed: int):
@@ -61,12 +62,13 @@ def compare(cfg: dict, sample_size: int, rows: int, seed: int, pairs,
     if not pairs:
         return {"max_gap": float("inf"), "tokens": 0}
     seqs, rows, toks, mask = _arrays(cfg, sample_size, rows, pairs)
-    h, head = reference.hidden(seed, cfg, seqs, rows)
+    hidden = spec.arch(cfg).hidden
+    h, head = hidden(seed, cfg, seqs, rows)
     gap, top = reference.gaps(h, head, toks)
     out = {"max_gap": float(gap[mask].max()), "tokens": int(mask.sum()),
            "argmax_share": float((toks[mask] == top[mask]).mean())}
     if control:
-        hc, _ = reference.hidden(seed, cfg, seqs, rows, quant="fp8")
+        hc, _ = hidden(seed, cfg, seqs, rows, quant="fp8")
         _, ctop = reference.gaps(hc, head, toks, quant="fp8")
         cgap, _ = reference.gaps(h, head, ctop)
         out["control_max_gap"] = float(cgap[mask].max())

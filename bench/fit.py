@@ -29,7 +29,7 @@ def main(argv=None) -> None:
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    from bench import system
+    from bench import spec
     from bench import weights as W
     from repro.serve.engine import ContinuousEngine
     from repro.models.transformer import Model
@@ -49,9 +49,10 @@ def main(argv=None) -> None:
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip),
             tree)
 
-    model = Model(system.model_config(cfg))
+    a = spec.arch(cfg)
+    model = Model(a.model_config(cfg))
     dtype = jnp.dtype(cfg["torch_dtype"])
-    params = sds(jax.eval_shape(lambda k: system.to_program(
+    params = sds(jax.eval_shape(lambda k: a.to_program(
         W.all_weights(k, cfg, dtype), cfg), jax.random.PRNGKey(0)))
     slots, max_seq = e["slots"], e["max_seq"]
     cache = sds(jax.eval_shape(lambda: model.init_paged_cache(

@@ -31,3 +31,12 @@ def within(outer, spans) -> List:
     """The spans of ``spans`` on ``outer``'s thread lying inside it."""
     return [s for s in spans if s.tid == outer.tid
             and outer.t0_ns <= s.t0_ns and s.t1_ns <= outer.t1_ns]
+
+
+def by_boundary(ctx):
+    """Each traced boundary with the spans and instants that end within
+    it."""
+    sp = window(ctx)
+    for b in ctx.traced:
+        t0, t1 = b.t0 * 1e9, b.t1 * 1e9
+        yield b, [s for s in sp if t0 <= s.t1_ns <= t1]

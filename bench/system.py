@@ -3,51 +3,23 @@
 ``repro.models.transformer.Model``, driven through ``submit`` and
 ``step_chunk``.
 
-This is the one module of the benchmark that imports the program.  Beyond
-the public surface it reads the scheduler's per-request outputs after each
-boundary (the engine has no token stream) and the scheduler's slot count,
-and it warms the engine's eager prompt padding once per prompt length.
+This is the one module of the benchmark that drives the program; the
+model it builds and the parameter tree come from the configuration's
+architecture (``bench/archs/``).  Beyond the public surface it reads the
+scheduler's per-request outputs after each boundary (the engine has no
+token stream) and the scheduler's slot count, and it warms the engine's
+eager prompt padding once per prompt length.
 """
 from __future__ import annotations
 
 import jax
 import numpy as np
 
+from bench import spec
 from bench import weights as W
-
-# the jitted programs' names as they appear in a device trace
-DECODE_PROGRAM = "chunk_fn"
-PREFILL_PROGRAM = "fn"
-
-
-def model_config(cfg: dict):
-    from repro.models.common import ModelConfig
-    return ModelConfig(
-        name=cfg["name"], family="dense",
-        n_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_key_value_heads"],
-        d_ff=cfg["intermediate_size"], vocab=cfg["vocab_size"],
-        head_dim=W.head_dim(cfg), qk_norm=bool(cfg.get("qk_norm")),
-        qkv_bias=bool(cfg.get("attention_bias")),
-        rope_theta=float(cfg["rope_theta"]),
-        norm_eps=float(cfg["rms_norm_eps"]), dtype=cfg["torch_dtype"],
-        max_seq=cfg["engine"]["max_seq"])
-
-
-def to_program(w: dict, cfg: dict) -> dict:
-    """The benchmark's weights as the program's parameter tree."""
-    from repro.models.attention import AttnParams
-    from repro.models.ffn import MlpParams
-    from repro.models.transformer import BlockParams
-    L = w["layers"]
-    qk = bool(cfg.get("qk_norm"))
-    attn = AttnParams(L["wq"], L["wk"], L["wv"], L["wo"], None, None, None,
-                      L["q_norm"] if qk else None, L["k_norm"] if qk else None)
-    return {"embed": w["embed"], "ln_f": w["ln_f"], "head": w["head"],
-            "blocks": BlockParams(L["ln1"], attn, L["ln2"],
-                                  MlpParams(L["w_gate"], L["w_up"],
-                                            L["w_down"]))}
+# the programs' names live in bench/spec.py, so that a metric reader need
+# not import this module
+from bench.spec import DECODE_PROGRAM, PREFILL_PROGRAM  # noqa: F401
 
 
 class Engine:
@@ -60,9 +32,10 @@ class Engine:
         e = cfg["engine"]
         if e["block_size"] != 16:
             raise ValueError("make_engine pages in blocks of 16 positions")
-        self.model = Model(model_config(cfg))
+        a = spec.arch(cfg)
+        self.model = Model(a.model_config(cfg))
         dtype = jax.numpy.dtype(cfg["torch_dtype"])
-        make = jax.jit(lambda key: to_program(
+        make = jax.jit(lambda key: a.to_program(
             W.all_weights(key, cfg, dtype), cfg))
         params = make(W.seed_key(seed))
         jax.block_until_ready(params)

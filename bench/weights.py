@@ -7,8 +7,11 @@ same few exactly-rounded float operations in any program, so the stacked
 call that makes the served weights on the device and the per-layer call
 that the reference makes later give the same bits.
 
-Names here are the benchmark's own; ``bench/system.py`` maps them onto the
-program's parameter tree, and the reference reads them directly.
+Which tensors there are, their shapes and which of them are norm scales
+is the architecture's (``bench/archs/``): its ``LAYER_LEAVES`` and
+``TOP_LEAVES`` give each name its place in the key, its ``to_program``
+maps the names onto the program's parameter tree, and its reference reads
+them directly.
 """
 from __future__ import annotations
 
@@ -17,12 +20,8 @@ import math
 import jax
 import jax.numpy as jnp
 
-# (name, shape from the widths, std) of one layer's tensors; the order is
-# part of each tensor's key and never changes.
-LAYER_LEAVES = ("ln1", "wq", "wk", "wv", "wo", "q_norm", "k_norm", "ln2",
-                "w_gate", "w_up", "w_down")
-TOP_LEAVES = ("embed", "ln_f", "head")
-NORMS = ("ln1", "ln2", "q_norm", "k_norm", "ln_f")
+from bench import spec
+
 NORM_STD = 0.1        # norm scales are 1 + N(0, 0.1^2)-like, never all ones
 EMBED_STD = 0.02
 
@@ -37,32 +36,9 @@ def seed_key(seed: int):
     return jax.random.fold_in(key, seed >> 32)
 
 
-def layer_shapes(cfg) -> dict:
-    d, nh, nkv, hd, ff = (cfg["hidden_size"], cfg["num_attention_heads"],
-                          cfg["num_key_value_heads"], head_dim(cfg),
-                          cfg["intermediate_size"])
-    shapes = {"ln1": (d,), "wq": (d, nh * hd), "wk": (d, nkv * hd),
-              "wv": (d, nkv * hd), "wo": (nh * hd, d), "ln2": (d,),
-              "w_gate": (d, ff), "w_up": (d, ff), "w_down": (ff, d)}
-    if cfg.get("qk_norm"):
-        shapes["q_norm"] = (hd,)
-        shapes["k_norm"] = (hd,)
-    return shapes
-
-
-def top_shapes(cfg) -> dict:
-    d, v = cfg["hidden_size"], cfg["vocab_size"]
-    return {"embed": (v, d), "ln_f": (d,), "head": (d, v)}
-
-
-def head_dim(cfg) -> int:
-    return cfg.get("head_dim") or cfg["hidden_size"] // cfg[
-        "num_attention_heads"]
-
-
-def _draw(key, name: str, shape, dtype):
+def _draw(key, name: str, shape, dtype, norms):
     u = jax.random.uniform(key, shape, jnp.float32, -1.0, 1.0)
-    if name in NORMS:
+    if name in norms:
         w = 1.0 + u * (NORM_STD * math.sqrt(3.0))
     else:
         std = EMBED_STD if name == "embed" else 1.0 / math.sqrt(shape[0])
@@ -76,16 +52,18 @@ def _leaf_key(key, names, name):
 
 def layer_weights(key, cfg, layer, dtype=jnp.bfloat16) -> dict:
     """One layer's tensors; ``layer`` may be traced."""
+    a = spec.arch(cfg)
     lk = jax.random.fold_in(key, 1)
-    return {n: _draw(jax.random.fold_in(_leaf_key(lk, LAYER_LEAVES, n),
-                                        layer), n, s, dtype)
-            for n, s in layer_shapes(cfg).items()}
+    return {n: _draw(jax.random.fold_in(_leaf_key(lk, a.LAYER_LEAVES, n),
+                                        layer), n, s, dtype, a.NORMS)
+            for n, s in a.layer_shapes(cfg).items()}
 
 
 def top_weights(key, cfg, dtype=jnp.bfloat16) -> dict:
+    a = spec.arch(cfg)
     tk = jax.random.fold_in(key, 0)
-    return {n: _draw(_leaf_key(tk, TOP_LEAVES, n), n, s, dtype)
-            for n, s in top_shapes(cfg).items()}
+    return {n: _draw(_leaf_key(tk, a.TOP_LEAVES, n), n, s, dtype, a.NORMS)
+            for n, s in a.top_shapes(cfg).items()}
 
 
 def all_weights(key, cfg, dtype=jnp.bfloat16) -> dict:

@@ -1,19 +1,19 @@
-"""Small configurations of the real cells for the benchmark's CPU tests:
-the same code paths and checks at smoke widths, in float32."""
+"""The cells of ``BENCHMARK.json`` for the benchmark's CPU tests, and each
+at the smoke widths of its architecture (``bench/archs/``): the same code
+paths and checks, in float32, at a size the CPU can run."""
 import copy
 
-SMOKE_WIDTHS = dict(hidden_size=64, intermediate_size=128,
-                    num_hidden_layers=2, num_attention_heads=4,
-                    num_key_value_heads=2, head_dim=16, vocab_size=256)
+from bench import spec
+
+CELLS = [w["name"] for w in spec.benchmark()["workloads"]]
 
 
 def smoke_cell(name: str, dtype: str = "float32") -> dict:
     """The cell ``name`` at smoke widths, a 256-position engine and short
     traffic: same code paths, same check, a size the CPU can run."""
-    from bench import spec
     c = copy.deepcopy(spec.cell(name))
     cfg = c["config"]
-    cfg.update(SMOKE_WIDTHS, torch_dtype=dtype)
+    cfg.update(spec.arch(cfg).SMOKE, torch_dtype=dtype)
     cfg["engine"].update(max_seq=256, slots=4, kv_blocks=4 * 16)
     t = c["traffic"]
     t["prompt"].update(min=16, max=120, median=40)

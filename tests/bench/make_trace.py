@@ -22,9 +22,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 
 
 def smoke_config(cfg: dict) -> dict:
-    cfg = dict(cfg, hidden_size=64, intermediate_size=128,
-               num_hidden_layers=2, num_attention_heads=4,
-               num_key_value_heads=2, head_dim=16, vocab_size=256)
+    from bench import spec
+    cfg = dict(cfg, **spec.arch(cfg).SMOKE)
     cfg["engine"] = dict(cfg["engine"], max_seq=256, slots=4,
                          kv_blocks=4 * 16)
     return cfg

@@ -11,6 +11,7 @@ import sys
 import pytest
 
 from bench import spec
+from bench_cells import CELLS
 
 ROOT = spec.ROOT
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -65,6 +66,9 @@ def test_benchmark_json_follows_its_contract():
         assert set(c) == {"name", "source", "file", "reduced", "why"}
         f = json.load(open(os.path.join(ROOT, c["file"])))
         assert f["name"] == c["name"] and f["reduced"] == c["reduced"]
+        assert os.path.isfile(os.path.join(ROOT, "bench", "archs",
+                                           f["architecture"] + ".py"))
+        spec.arch(f)
         assert not any(k.endswith(("_dim", "_rank", "_size"))
                        for k in c["reduced"])
     for w in b["workloads"]:
@@ -91,7 +95,7 @@ def test_benchmark_json_follows_its_contract():
         assert len(c["end_to_end"]) >= 2 and c["per_layer"]
 
 
-@pytest.mark.parametrize("name", ["qwen3-4b.decode", "yi-9b-l24.rag"])
+@pytest.mark.parametrize("name", CELLS)
 def test_each_cell_fits_its_engine(name):
     c = spec.cell(name)
     e, t = c["config"]["engine"], c["traffic"]

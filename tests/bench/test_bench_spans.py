@@ -89,3 +89,17 @@ def test_reader_finds_nothing_for_the_fake_engine(name):
     ctx = harness.Ctx({}, CPU_PEAKS, win, None, win.boundaries[a:b + 1])
     assert spec.metric_reader(name)(ctx) is None
     assert spec.metric_reader(name)(_ctx([])) is None
+
+
+def test_by_boundary_hands_each_boundary_its_own_spans(recorded):
+    from bench import spans as S
+    ctx = recorded([_span("serve.step_chunk", 10, 30),
+                    _span("serve.device_wait", 380, 420),   # ends in the 2nd
+                    _span("serve.step_chunk", 500, 504),
+                    _span("serve.step_chunk", 1100, 1200)])  # after both
+    got = [(b.t0, [(s.name, s.t1_ns) for s in sp])
+           for b, sp in S.by_boundary(ctx)]
+    assert got == [
+        (T0 / 1e9, [("serve.step_chunk", T0 + 30 * MS)]),
+        ((T0 + 400 * MS) / 1e9, [("serve.device_wait", T0 + 420 * MS),
+                                 ("serve.step_chunk", T0 + 504 * MS)])]

@@ -4,9 +4,10 @@ import numpy as np
 import pytest
 
 from bench import spec, traffic
+from bench_cells import CELLS
 
 
-@pytest.mark.parametrize("cell", ["qwen3-4b.decode", "yi-9b-l24.rag"])
+@pytest.mark.parametrize("cell", CELLS)
 def test_generator_is_deterministic_for_a_seed(cell):
     t = spec.cell(cell)["traffic"]
     a = traffic.make(t, 2**40 + 3, 1000, 30.0)
@@ -18,7 +19,7 @@ def test_generator_is_deterministic_for_a_seed(cell):
         (o, r.idx) for o, r in b.start()]
 
 
-@pytest.mark.parametrize("cell", ["qwen3-4b.decode", "yi-9b-l24.rag"])
+@pytest.mark.parametrize("cell", CELLS)
 def test_lengths_keep_their_clips(cell):
     t = spec.cell(cell)["traffic"]
     g = traffic.make(t, 7, 1000, 51.0)
@@ -32,7 +33,7 @@ def test_lengths_keep_their_clips(cell):
                for r in g.requests)
 
 
-@pytest.mark.parametrize("cell", ["qwen3-4b.decode", "yi-9b-l24.rag"])
+@pytest.mark.parametrize("cell", CELLS)
 def test_every_seed_gets_the_same_schedule_and_other_tokens(cell):
     t = spec.cell(cell)["traffic"]
     a = traffic.make(t, 1, 1000, 51.0)
