@@ -61,17 +61,22 @@ OP_SIZES = {"n": 1 << 22, "mm": (1024, 2560, 9728), "rows": 4096, "d": 2560}
 # hand-written kernels at qwen3-4b serving shapes (a 1024-token prefill)
 KERNEL_SIZES = {"tokens": 1024, "d": 2560, "ff": 9728, "heads": 32,
                 "kv_heads": 8, "head_dim": 128}
-# paged decode at the pools of the benchmark's cells (bench/configs): 8
-# slots, 32 query heads of 128, 16-position pages; qwen3-4b's 36 layers of
-# 528 pages and 8 kv heads over 2048 positions, yi-9b-l24's 24 of 2048 and
-# 4 over 4096
+# paged decode at the pools of the benchmark's cells (bench/configs): 32
+# query heads, 16-position pages; qwen3-4b's 8 slots, 36 layers of 528
+# pages and 8 kv heads of 128 over 2048 positions, yi-9b-l24's 8 slots,
+# 24 of 2048 and 4 over 4096; granite-4.0-h-micro's 32 slots, 4 attention
+# layers of 16384 pages and 8 kv heads of 64 (two to a 128-lane row) over
+# 8192 positions, scores scaled by 1/64
 PAGED_SIZES = ({"name": "qwen3-4b", "layers": 36, "pages": 528,
                 "kv_heads": 8, "max_seq": 2048},
                {"name": "yi-9b-l24", "layers": 24, "pages": 2048,
-                "kv_heads": 4, "max_seq": 4096})
+                "kv_heads": 4, "max_seq": 4096},
+               {"name": "granite-4.0-h-micro", "layers": 4, "pages": 16384,
+                "kv_heads": 8, "max_seq": 8192, "slots": 32, "head_dim": 64,
+                "scale": 1 / 64})
 PAGED_SLOTS, PAGED_HEADS, PAGED_HEAD_DIM, PAGED_BLOCK = 8, 32, 128, 16
-# a decode step's slot lengths, as shares of max_seq: six busy slots at
-# 22-40% of it, two parked
+# a decode step's slot lengths, as shares of max_seq, for every 8 slots:
+# six busy slots at 22-40% of it, two parked
 STEP_LEN_SHARES = (0.28, 0.34, 0.22, 0.4, 0.3, 0.34, 0.0, 0.0)
 
 
@@ -290,9 +295,12 @@ def paged_decode_check(shape: dict, close, interpret: bool) -> None:
     """The paged decode kernel against the jnp decode attention over the
     gathered view (``models.attention._attend_token``'s math) at one pool:
     lengths 1, 15, 16, 17, mid and full, a sentinel-padded tail and a
-    parked lane, at two layers other than 0; then a decode step's
-    attention over every layer at ``STEP_LEN_SHARES``, timed at the
-    kernel's pages per block and at two and four times as many."""
+    parked lane (and past 8 slots, lengths drawn up to full), at two
+    layers other than 0; then a decode step's attention over every layer
+    at ``STEP_LEN_SHARES``, timed at the kernel's pages per block and at
+    two and four times as many.  Heads narrower than 128 lanes are packed
+    into the pool's rows as the engine packs them
+    (``models.attention.pool_rows``)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -301,24 +309,32 @@ def paged_decode_check(shape: dict, close, interpret: bool) -> None:
     from repro.models.attention import gather_paged_view
 
     L, nb, nkv = shape["layers"], shape["pages"], shape["kv_heads"]
-    b, nh, hd, bs = PAGED_SLOTS, PAGED_HEADS, PAGED_HEAD_DIM, PAGED_BLOCK
+    b = shape.get("slots", PAGED_SLOTS)
+    hd = shape.get("head_dim", PAGED_HEAD_DIM)
+    scale = shape.get("scale", hd ** -0.5)
+    nh, bs = PAGED_HEADS, PAGED_BLOCK
     mb = shape["max_seq"] // bs
-    ks = jax.random.split(jax.random.PRNGKey(SEED + 1), 4)
+    per = 128 // hd if hd < 128 else 1           # kv heads a pool row
+    ks = jax.random.split(jax.random.PRNGKey(SEED + 1), 5)
     pool = (L, nb, bs, nkv, hd)
     kp = jax.random.normal(ks[0], pool, jnp.bfloat16)
     vp = jax.random.normal(ks[1], pool, jnp.bfloat16)
+    rows = pool[:3] + (nkv // per, hd * per)
+    kr, vr = kp.reshape(rows), vp.reshape(rows)
     q = jax.random.normal(ks[2], (b, nh, hd), jnp.bfloat16)
     qg = q.reshape(b, nkv, nh // nkv, hd)
     tail = mb * bs // 4 + 3
     bt = jax.random.randint(ks[3], (b, mb), 0, nb, jnp.int32)
     bt = bt.at[6, -(-tail // bs):].set(nb).at[7].set(nb)
-    lengths = jnp.asarray([1, 15, 16, 17, mb * bs // 2 + 5, mb * bs, tail,
-                           1], jnp.int32)
+    lengths = jnp.concatenate([
+        jnp.asarray([1, 15, 16, 17, mb * bs // 2 + 5, mb * bs, tail, 1],
+                    jnp.int32),
+        jax.random.randint(ks[4], (b - 8,), 1, mb * bs + 1, jnp.int32)])
 
     def view_attention(layer):
         vk, vv = gather_paged_view(kp[layer][None], vp[layer][None], bt)
         s = jnp.einsum("bngh,btnh->bngt", qg, vk[0],
-                       preferred_element_type=jnp.float32) / hd ** 0.5
+                       preferred_element_type=jnp.float32) * scale
         valid = jnp.arange(mb * bs) < lengths[:, None, None, None]
         p = jax.nn.softmax(jnp.where(valid, s, -jnp.inf), axis=-1)
         o = jnp.einsum("bngt,btnh->bngh", p.astype(vv.dtype), vv[0],
@@ -328,17 +344,17 @@ def paged_decode_check(shape: dict, close, interpret: bool) -> None:
     for layer in (1, L - 1):
         close(f"pallas paged_decode_attention ({shape['name']}, layer "
               f"{layer})",
-              paged_decode.paged_decode_attention(q, kp, vp, jnp.int32(layer),
-                                                  lengths, bt),
+              paged_decode.paged_decode_attention(q, kr, vr, jnp.int32(layer),
+                                                  lengths, bt, scale=scale),
               lambda: view_attention(layer), 2e-2)
 
     lens = jnp.asarray([max(1, round(f * shape["max_seq"]))
-                        for f in STEP_LEN_SHARES], jnp.int32)
+                        for f in STEP_LEN_SHARES * (b // 8)], jnp.int32)
     valid_bytes = int(lens.sum()) * L * 2 * nkv * hd * kp.dtype.itemsize
     reps = 1 if interpret else 20
     ppb0 = paged_decode._pages_per_block(bs)
     for ppb in (ppb0, 2 * ppb0, 4 * ppb0):
-        call = functools.partial(paged_decode._call, ppb=ppb,
+        call = functools.partial(paged_decode._call, ppb=ppb, scale=scale,
                                  interpret=interpret)
 
         @jax.jit
@@ -347,7 +363,7 @@ def paged_decode_check(shape: dict, close, interpret: bool) -> None:
                 return c + call(qg, kp, vp, li, lens, bt).sum(), None
             return jax.lax.scan(layer, jnp.float32(0), jnp.arange(L))[0]
 
-        out = step(qg, kp, vp, lens, bt)
+        out = step(qg, kr, vr, lens, bt)
         if not np.isfinite(float(out)):
             fail(f"paged decode step at {ppb} pages per block: non-finite")
         t = time.perf_counter()

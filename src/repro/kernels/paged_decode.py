@@ -2,19 +2,24 @@
 
 One query token per slot attends to that slot's pages of one layer, read
 through its block-table row.  The pools keep the serving layout
-``(L, n_blocks, block_size, nkv, hd)`` and stay in HBM; ``layer``,
+``(L, n_blocks, block_size, rows, lanes)`` and stay in HBM: a row is one
+kv head of ``hd`` lanes, or, for heads narrower than 128 lanes, ``lanes //
+hd`` heads side by side (``models.attention.pool_rows``); ``layer``,
 ``lengths`` and the block tables arrive as scalar prefetch.  Grid step
 ``i`` (slot ``i``) copies only the first ``ceil(lengths[i] / block_size)``
 pages of its row into VMEM, a block of pages (at least 128 positions) at a
 time, with the next block's copies (the next slot's first, after a slot's
 last) started before the current block is computed.
 
-A page holds every kv head of its ``block_size`` positions, so a block of
-pages is ``(positions * nkv, hd)`` rows with kv head ``n`` of position
-``t`` at row ``t * nkv + n``: each head is the strided row set
-``n::nkv``, lane-aligned when ``hd`` is a multiple of 128.  In a bfloat16
-block two heads share each 32-bit word (the even head in the low half), so
-heads are read in pairs through a 32-bit view and split by shifting.
+A page holds every row of its ``block_size`` positions, so a block of
+pages is ``(positions * rows, lanes)`` with row ``n`` of position ``t`` at
+``t * rows + n``: each row is the strided set ``n::rows``, lane-aligned
+when ``lanes`` is a multiple of 128.  In a bfloat16 block two rows share
+each 32-bit word (the even row in the low half), so rows are read in pairs
+through a 32-bit view and split by shifting.  Where a row holds several
+heads, kv head ``n`` is lanes ``[(n % per) * hd, (n % per + 1) * hd)`` of
+row ``n // per``, sliced out of the row inside the kernel; queries and
+output keep the heads' own layout ``(nkv, group, hd)``.
 
 Per kv head the GQA group's queries run the online-softmax recurrence of
 ``flash_attention.py`` in float32; the PV product takes probabilities in
@@ -23,7 +28,8 @@ does.  Positions past a slot's length are masked; copy slots of a block
 that hold no page of this slot keep the finite rows of an earlier copy
 (the buffers are zeroed once per call), so a masked row weighs zero.
 Validated against the gathered-view path in interpret mode
-(``tests/test_paged_decode_kernel.py``).
+(``tests/test_paged_decode_kernel.py``) and on the chip at the benchmark's
+pools (``chip_smoke.py``).
 
 The mode follows the lowering platform: the Mosaic kernel where the
 program is compiled for a TPU (also from a CPU host, for a described
@@ -48,35 +54,41 @@ MIN_BLOCK_POSITIONS = 128
 
 
 def supports(shape, dtype) -> bool:
-    """Whether a pool of ``shape`` (L, n_blocks, block_size, nkv, hd) and
-    ``dtype`` has the layout the Mosaic kernel reads: lane-aligned heads,
-    and for bfloat16 an even number of heads (they are read in pairs)."""
-    nkv, hd = shape[3], shape[4]
+    """Whether a pool of ``shape`` (L, n_blocks, block_size, rows, lanes)
+    and ``dtype`` has the layout the Mosaic kernel reads: lane-aligned
+    rows, and for bfloat16 an even number of rows (they are read in
+    pairs)."""
+    rows, lanes = shape[3], shape[4]
     dtype = jnp.dtype(dtype)
-    if hd % 128:
+    if lanes % 128:
         return False
     if dtype == jnp.float32:
         return True
-    return dtype == jnp.bfloat16 and nkv % 2 == 0
+    return dtype == jnp.bfloat16 and rows % 2 == 0
 
 
 def _pages_per_block(block_size: int) -> int:
     return max(1, -(-MIN_BLOCK_POSITIONS // block_size))
 
 
-def _kv_heads(ref, nkv: int, rows: int):
-    """Kv head n's ``(rows, hd)`` values, for every n, from a block ref of
-    ``(rows * nkv, hd)`` rows."""
+def _kv_heads(ref, nkv: int, rows: int, per: int = 1):
+    """Kv head n's ``(rows, lanes // per)`` values, for every n, from a
+    block ref of ``(rows * nkv, lanes)`` pool rows holding ``per`` heads
+    each (``nkv`` counts the rows)."""
     if jnp.dtype(ref.dtype).itemsize == 4:
-        return [ref[pl.ds(n, rows, stride=nkv), :] for n in range(nkv)]
-    words = ref.bitcast(jnp.uint32)          # (rows * nkv / 2, hd)
-    heads = []
-    for w in range(nkv // 2):
-        b = words[pl.ds(w, rows, stride=nkv // 2), :]
-        heads.append(pltpu.bitcast(b << 16, jnp.float32).astype(ref.dtype))
-        heads.append(pltpu.bitcast(b & jnp.uint32(0xFFFF0000),
-                                   jnp.float32).astype(ref.dtype))
-    return heads
+        heads = [ref[pl.ds(n, rows, stride=nkv), :] for n in range(nkv)]
+    else:
+        words = ref.bitcast(jnp.uint32)      # (rows * nkv / 2, lanes)
+        heads = []
+        for w in range(nkv // 2):
+            b = words[pl.ds(w, rows, stride=nkv // 2), :]
+            heads.append(pltpu.bitcast(b << 16, jnp.float32).astype(ref.dtype))
+            heads.append(pltpu.bitcast(b & jnp.uint32(0xFFFF0000),
+                                       jnp.float32).astype(ref.dtype))
+    if per == 1:
+        return heads
+    hd = ref.shape[1] // per
+    return [h[:, k * hd:(k + 1) * hd] for h in heads for k in range(per)]
 
 
 def _paged_decode_kernel(layer_ref, len_ref, bt_ref, q_ref, k_hbm, v_hbm,
@@ -86,6 +98,8 @@ def _paged_decode_kernel(layer_ref, len_ref, bt_ref, q_ref, k_hbm, v_hbm,
     i = pl.program_id(0)
     n_slots = pl.num_programs(0)
     nkv, group, hd = q_ref.shape
+    n_rows, lanes = k_buf.shape[3], k_buf.shape[4]
+    per = lanes // hd                  # kv heads a pool row
     rows = ppb * block_size
     layer = layer_ref[0]
 
@@ -146,8 +160,10 @@ def _paged_decode_kernel(layer_ref, len_ref, bt_ref, q_ref, k_hbm, v_hbm,
             start(i + 1, 0, 1 - buf)
 
         wait(i, j, buf)
-        ks = _kv_heads(k_buf.at[buf].reshape(rows * nkv, hd), nkv, rows)
-        vs = _kv_heads(v_buf.at[buf].reshape(rows * nkv, hd), nkv, rows)
+        ks = _kv_heads(k_buf.at[buf].reshape(rows * n_rows, lanes), n_rows,
+                       rows, per)
+        vs = _kv_heads(v_buf.at[buf].reshape(rows * n_rows, lanes), n_rows,
+                       rows, per)
         kpos = j * rows + jax.lax.broadcasted_iota(jnp.int32, (group, rows),
                                                    1)
         valid = kpos < length
@@ -179,14 +195,15 @@ def _paged_decode_kernel(layer_ref, len_ref, bt_ref, q_ref, k_hbm, v_hbm,
 
 
 def _call(q, k_pool, v_pool, layer, lengths, block_tables, *, ppb: int,
-          interpret: bool):
+          interpret: bool, scale=None):
     b, nkv, group, hd = q.shape
     _, n_blocks, block_size, _, _ = k_pool.shape
     max_blocks = block_tables.shape[1]
     kernel = functools.partial(
         _paged_decode_kernel, ppb=ppb, block_size=block_size,
-        n_blocks=n_blocks, max_blocks=max_blocks, scale=1.0 / math.sqrt(hd))
-    buf = (2, ppb, block_size, nkv, hd)
+        n_blocks=n_blocks, max_blocks=max_blocks,
+        scale=1.0 / math.sqrt(hd) if scale is None else scale)
+    buf = (2, ppb, block_size) + k_pool.shape[3:]
     slot = pl.BlockSpec((None, nkv, group, hd), lambda i, *_: (i, 0, 0, 0))
     return pl.pallas_call(
         kernel,
@@ -213,21 +230,25 @@ def _call(q, k_pool, v_pool, layer, lengths, block_tables, *, ppb: int,
       q, k_pool, v_pool)
 
 
-@jax.jit
-def paged_decode_attention(q, k_pool, v_pool, layer, lengths, block_tables):
+@functools.partial(jax.jit, static_argnames=("scale",))
+def paged_decode_attention(q, k_pool, v_pool, layer, lengths, block_tables,
+                           *, scale=None):
     """Decode attention of one token per slot against its valid pages.
 
-    q: (b, nh, hd); k_pool/v_pool: (L, n_blocks, block_size, nkv, hd);
-    layer: int32 scalar; lengths: (b,) int32, each at least 1 — slot ``i``
-    attends positions ``[0, lengths[i])``; block_tables: (b, max_blocks)
-    int32, entries clipped to ``[0, n_blocks)``.  Returns (b, nh, hd)
-    float32, query head ``h`` attending kv head ``h // (nh // nkv)``."""
+    q: (b, nh, hd); k_pool/v_pool: (L, n_blocks, block_size, rows, lanes)
+    holding ``lanes // hd`` kv heads a row; layer: int32 scalar; lengths:
+    (b,) int32, each at least 1 — slot ``i`` attends positions
+    ``[0, lengths[i])``; block_tables: (b, max_blocks) int32, entries
+    clipped to ``[0, n_blocks)``; scale: of the scores, 1/sqrt(hd) by
+    default.  Returns (b, nh, hd) float32, query head ``h`` attending kv
+    head ``h // (nh // nkv)``."""
     b, nh, hd = q.shape
-    nkv = k_pool.shape[3]
+    nkv = k_pool.shape[3] * (k_pool.shape[4] // hd)
     ppb = _pages_per_block(k_pool.shape[2])
     qg = q.reshape(b, nkv, nh // nkv, hd)
     out = jax.lax.platform_dependent(
         qg, k_pool, v_pool, layer, lengths, block_tables,
-        tpu=functools.partial(_call, ppb=ppb, interpret=False),
-        default=functools.partial(_call, ppb=ppb, interpret=True))
+        tpu=functools.partial(_call, ppb=ppb, scale=scale, interpret=False),
+        default=functools.partial(_call, ppb=ppb, scale=scale,
+                                  interpret=True))
     return out.reshape(b, nh, hd)

@@ -60,10 +60,20 @@ def _project_qkv(p: AttnParams, cfg: ModelConfig, x, positions):
     if p.q_norm is not None:
         q = rmsnorm(q, p.q_norm, cfg.norm_eps)
         k = rmsnorm(k, p.k_norm, cfg.norm_eps)
-    cos, sin = rope_freqs(hd, cfg.rope_theta, positions)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    if cfg.rope:
+        cos, sin = rope_freqs(hd, cfg.rope_theta, positions)
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
     return q, k, v
+
+
+def _scaled(scores, cfg: ModelConfig):
+    """Attention scores times the configured scale (divided by
+    sqrt(head_dim) where none is configured)."""
+    mult = getattr(cfg, "attention_multiplier", None)
+    if mult is None:
+        return scores / jnp.sqrt(float(cfg.hd))
+    return scores * mult
 
 
 NEG_INF = -1e30
@@ -75,18 +85,20 @@ KV_CHUNK = 1024
 
 
 def chunked_attention(q, k, v, *, causal: bool = True, q_offset: int = 0,
-                      kv_chunk: int = KV_CHUNK):
+                      kv_chunk: int = KV_CHUNK, scale=None):
     """Online-softmax attention over KV chunks (lax.scan) — the XLA-lowerable
     flash attention used for training/prefill roofline paths; the Pallas
     kernel (kernels/flash_attention.py) is the TPU in-kernel version of the
     same recurrence.
 
-    q: (b, sq, nh, hd); k/v: (b, sk, nkv, hd); GQA via nh % nkv == 0.
+    q: (b, sq, nh, hd); k/v: (b, sk, nkv, hd); GQA via nh % nkv == 0;
+    ``scale`` of the scores, 1/sqrt(hd) by default.
     """
     b, sq, nh, hd = q.shape
     sk, nkv = k.shape[1], k.shape[2]
     group = nh // nkv
-    scale = 1.0 / jnp.sqrt(float(hd))
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(float(hd))
     # keep q/k/v in their storage dtype; accumulate dots in f32 on the MXU
     # (f32-converting the inputs materialises f32 copies of the whole k/v)
     qg = (q * jnp.asarray(scale, q.dtype)).reshape(b, sq, nkv, group, hd)
@@ -141,12 +153,14 @@ def attention(p: AttnParams, cfg: ModelConfig, x, positions):
         of = ops.flash_attention(qf, kf, vf, causal=True, impl="pallas")
         out = of.reshape(b, nh, s, hd).transpose(0, 2, 1, 3)
     elif s > CHUNKED_THRESHOLD and s % KV_CHUNK == 0:
-        out = chunked_attention(q, k, v, causal=True)
+        out = chunked_attention(q, k, v, causal=True,
+                                scale=cfg.attention_multiplier)
     else:
         group = nh // nkv
         qg = q.reshape(b, s, nkv, group, hd)
-        scores = jnp.einsum("bsngh,btnh->bngst", qg.astype(jnp.float32),
-                            k.astype(jnp.float32)) / jnp.sqrt(float(hd))
+        scores = _scaled(jnp.einsum("bsngh,btnh->bngst",
+                                    qg.astype(jnp.float32),
+                                    k.astype(jnp.float32)), cfg)
         mask = jnp.tril(jnp.ones((s, s), bool))
         scores = jnp.where(mask[None, None, None], scores, -jnp.inf)
         probs = jax.nn.softmax(scores, axis=-1)
@@ -191,9 +205,8 @@ def _attend_token(cfg: ModelConfig, q, k_l, v_l, pos, per_slot: bool,
     nh, nkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
     group = nh // nkv
     qg = q.reshape(b, nkv, group, hd)
-    scores = jnp.einsum("bngh,btnh->bngt", qg, k_l,
-                        preferred_element_type=jnp.float32) \
-        / jnp.sqrt(float(hd))
+    scores = _scaled(jnp.einsum("bngh,btnh->bngt", qg, k_l,
+                                preferred_element_type=jnp.float32), cfg)
     t = k_l.shape[1]
     kpos = jnp.arange(t)[None, None, None, :]
     valid = kpos <= (pos[:, None, None, None] if per_slot else pos)
@@ -262,10 +275,34 @@ def attention_decode_inplace(p: AttnParams, cfg: ModelConfig, x, ck, cv,
 # ---------------------------------------------------------------------------
 
 
+def pool_rows(cfg: ModelConfig) -> Tuple[int, int]:
+    """(rows, lanes) of one position's K (or V) in a page.  Heads narrower
+    than a 128-lane vector register are packed ``128 // hd`` to a row
+    (kv head ``n`` in row ``n // (128 // hd)``), so the pool keeps the TPU's
+    lane-dense layout and the paged kernel reads whole rows; other widths
+    keep one head a row, ``(nkv, hd)``."""
+    nkv, hd = cfg.n_kv_heads, cfg.hd
+    per = 128 // hd if hd < 128 and 128 % hd == 0 else 1
+    if per > 1 and nkv % per == 0:
+        return nkv // per, hd * per
+    return nkv, hd
+
+
+def _to_rows(cfg: ModelConfig, x):
+    """(..., nkv, hd) -> the pool's (..., rows, lanes)."""
+    return x.reshape(x.shape[:-2] + pool_rows(cfg))
+
+
+def _to_heads(cfg: ModelConfig, x):
+    """The pool's (..., rows, lanes) -> (..., nkv, hd)."""
+    return x.reshape(x.shape[:-2] + (cfg.n_kv_heads, cfg.hd))
+
+
 def init_paged_kv(cfg: ModelConfig, n_blocks: int, block_size: int
                   ) -> KVCache:
-    """A paged KV pool: ``(n_blocks, block_size, nkv, hd)`` pages."""
-    shape = (n_blocks, block_size, cfg.n_kv_heads, cfg.hd)
+    """A paged KV pool: ``(n_blocks, block_size, rows, lanes)`` pages,
+    :func:`pool_rows` of each position."""
+    shape = (n_blocks, block_size) + pool_rows(cfg)
     return KVCache(jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype))
 
 
@@ -287,8 +324,8 @@ def _pages_for_positions(pos, bt_row, block_size: int, n_blocks: int):
 def _gather_pages(pool, bt):
     """Gather a per-slot logical view from a page pool.
 
-    pool: (n_blocks, block_size, nkv, hd); bt: (b, max_blocks) int32.
-    Returns (b, max_blocks * block_size, nkv, hd).  Sentinel entries clip
+    pool: (n_blocks, block_size, rows, lanes); bt: (b, max_blocks) int32.
+    Returns (b, max_blocks * block_size, rows, lanes).  Sentinel entries clip
     to an arbitrary real page — their positions are beyond every valid
     ``kpos <= pos`` mask, so the values are never attended; clipping keeps
     the gather maskless on the hot path."""
@@ -306,8 +343,8 @@ def _masked_attend(cfg: ModelConfig, q, k_all, v_all, qpos, kpos):
     nkv = k_all.shape[2]
     group = nh // nkv
     qg = q.reshape(b, sq, nkv, group, hd)
-    scores = jnp.einsum("bsngh,btnh->bngst", qg.astype(jnp.float32),
-                        k_all.astype(jnp.float32)) / jnp.sqrt(float(hd))
+    scores = _scaled(jnp.einsum("bsngh,btnh->bngst", qg.astype(jnp.float32),
+                                k_all.astype(jnp.float32)), cfg)
     valid = kpos[:, None, None, None, :] <= qpos[:, None, None, :, None]
     scores = jnp.where(valid, scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1)
@@ -346,7 +383,7 @@ def paged_attention_prefill(p: AttnParams, cfg: ModelConfig, x, ck, cv, li,
                             bt_row, start, *, first: bool):
     """Prefill one prompt chunk into LAYER-STACKED page pools.
 
-    x: (1, s, d); ck/cv: (L, n_blocks, block_size, nkv, hd); bt_row: the
+    x: (1, s, d); ck/cv: (L, n_blocks, block_size, rows, lanes); bt_row: the
     slot's (max_blocks,) block-table row; start: chunk offset (traced ok).
     k/v are scattered page-by-page (writes through sentinel/overflow
     entries drop — ``mode='drop'``, the dense out-of-range discipline).
@@ -362,15 +399,17 @@ def paged_attention_prefill(p: AttnParams, cfg: ModelConfig, x, ck, cv, li,
     positions = start + jnp.arange(s)[None, :]
     q, k, v = _project_qkv(p, cfg, x, positions)
     page, off = _pages_for_positions(positions[0], bt_row, bs, nb)
-    ck = ck.at[li, page, off].set(k[0].astype(ck.dtype), mode="drop")
-    cv = cv.at[li, page, off].set(v[0].astype(cv.dtype), mode="drop")
+    ck = ck.at[li, page, off].set(_to_rows(cfg, k[0]).astype(ck.dtype),
+                                  mode="drop")
+    cv = cv.at[li, page, off].set(_to_rows(cfg, v[0]).astype(cv.dtype),
+                                  mode="drop")
     if first:
         out = attention(p, cfg, x, positions)
         return out, ck, cv
     k_l = jax.lax.dynamic_index_in_dim(ck, li, axis=0, keepdims=False)
     v_l = jax.lax.dynamic_index_in_dim(cv, li, axis=0, keepdims=False)
-    k_all = _gather_pages(k_l, bt_row[None])
-    v_all = _gather_pages(v_l, bt_row[None])
+    k_all = _to_heads(cfg, _gather_pages(k_l, bt_row[None]))
+    v_all = _to_heads(cfg, _gather_pages(v_l, bt_row[None]))
     kpos = jnp.arange(k_all.shape[1])[None, :]
     out = _masked_attend(cfg, q, k_all, v_all, positions, kpos)
     out = out.astype(x.dtype).reshape(b, s, -1)
@@ -390,8 +429,10 @@ def _paged_write_token(p: AttnParams, cfg: ModelConfig, x, ck, cv, li, pos,
         pos = jnp.broadcast_to(pos, (b,))
     q, k, v = _project_qkv(p, cfg, x, pos[:, None])
     page, off = _page_slots(pos, bt, bs, nb)
-    ck = ck.at[li, page, off].set(k[:, 0].astype(ck.dtype), mode="drop")
-    cv = cv.at[li, page, off].set(v[:, 0].astype(cv.dtype), mode="drop")
+    ck = ck.at[li, page, off].set(_to_rows(cfg, k[:, 0]).astype(ck.dtype),
+                                  mode="drop")
+    cv = cv.at[li, page, off].set(_to_rows(cfg, v[:, 0]).astype(cv.dtype),
+                                  mode="drop")
     return pos, q, k, v, ck, cv
 
 
@@ -400,7 +441,7 @@ def paged_attention_decode_inplace(p: AttnParams, cfg: ModelConfig, x, ck,
     """One-token decode against layer-stacked page pools — the paged twin
     of :func:`attention_decode_inplace`'s per-slot path.
 
-    ck/cv: (L, n_blocks, block_size, nkv, hd); pos: (b,) per-slot
+    ck/cv: (L, n_blocks, block_size, rows, lanes); pos: (b,) per-slot
     positions; bt: (b, max_blocks) block tables.  The new token is written
     through the table (drop on sentinel/overflow — a retired or
     mid-prefill lane whose position was parked at ``max_seq`` writes
@@ -412,8 +453,8 @@ def paged_attention_decode_inplace(p: AttnParams, cfg: ModelConfig, x, ck,
     pos, q, _, _, ck, cv = _paged_write_token(p, cfg, x, ck, cv, li, pos, bt)
     k_l = jax.lax.dynamic_index_in_dim(ck, li, axis=0, keepdims=False)
     v_l = jax.lax.dynamic_index_in_dim(cv, li, axis=0, keepdims=False)
-    k_all = _gather_pages(k_l, bt)
-    v_all = _gather_pages(v_l, bt)
+    k_all = _to_heads(cfg, _gather_pages(k_l, bt))
+    v_all = _to_heads(cfg, _gather_pages(v_l, bt))
     return _attend_token(cfg, q, k_all, v_all, pos, True, x.dtype,
                          p.wo), ck, cv
 
@@ -421,13 +462,14 @@ def paged_attention_decode_inplace(p: AttnParams, cfg: ModelConfig, x, ck,
 def gather_paged_view(ck, cv, bt) -> Tuple[jax.Array, jax.Array]:
     """Materialise the per-slot logical view of layer-stacked page pools.
 
-    ck/cv: (L, n_blocks, block_size, nkv, hd); bt: (b, max_blocks).
-    Returns (L, b, max_blocks * block_size, nkv, hd) pairs — shaped exactly
-    like the dense layer-stacked cache, holding each slot's pages in
-    logical order.  The decode chunk gathers this ONCE per chunk and
-    updates it incrementally per token (:func:`paged_attention_decode_view`)
-    instead of re-gathering every step/layer — the page indirection is paid
-    per chunk, not per token."""
+    ck/cv: (L, n_blocks, block_size, rows, lanes); bt: (b, max_blocks).
+    Returns (L, b, max_blocks * block_size, rows, lanes) pairs, each
+    slot's pages in logical order — with one head a row, shaped exactly
+    like the dense layer-stacked cache (:meth:`Model.gather_paged_view`
+    unpacks packed rows to heads).  The decode chunk gathers this ONCE per
+    chunk and updates it incrementally per token
+    (:func:`paged_attention_decode_view`) instead of re-gathering every
+    step/layer — the page indirection is paid per chunk, not per token."""
     nb = ck.shape[1]
     safe = jnp.clip(bt, 0, nb - 1)
     L = ck.shape[0]
@@ -477,7 +519,7 @@ def paged_attention_decode_kernel(p: AttnParams, cfg: ModelConfig, x, ck,
     paged kernel: each slot reads only its valid pages, straight from the
     pool, and no view is gathered.
 
-    ck/cv: (L, n_blocks, block_size, nkv, hd); pos: (b,) per-slot
+    ck/cv: (L, n_blocks, block_size, rows, lanes); pos: (b,) per-slot
     positions; bt: (b, max_blocks).  The new token is scattered into the
     pool through the table as :func:`paged_attention_decode_view` does
     (dropping for a parked lane), then slot ``i`` attends positions
@@ -490,7 +532,8 @@ def paged_attention_decode_kernel(p: AttnParams, cfg: ModelConfig, x, ck,
     pos, q, _, _, ck, cv = _paged_write_token(p, cfg, x, ck, cv, li, pos, bt)
     lengths = jnp.where(pos < bt.shape[1] * ck.shape[2], pos + 1, 1)
     out = paged_decode.paged_decode_attention(
-        q[:, 0].astype(ck.dtype), ck, cv, li, lengths, bt)
+        q[:, 0].astype(ck.dtype), ck, cv, li, lengths, bt,
+        scale=cfg.attention_multiplier)
     out = out.astype(x.dtype).reshape(x.shape[0], 1, -1)
     return jnp.einsum("bsh,hd->bsd", out, p.wo), ck, cv
 
@@ -510,9 +553,8 @@ def attention_decode(p: AttnParams, cfg: ModelConfig, x, cache: KVCache,
     # bf16-in / f32-accumulate einsums: converting the whole cache to f32
     # materialised seq_len x hd x f32 copies per step (EXPERIMENTS.md Perf,
     # zamba2 iteration 1); preferred_element_type keeps accuracy on the MXU.
-    scores = jnp.einsum("bngh,btnh->bngt", qg, ck,
-                        preferred_element_type=jnp.float32) \
-        / jnp.sqrt(float(hd))
+    scores = _scaled(jnp.einsum("bngh,btnh->bngt", qg, ck,
+                                preferred_element_type=jnp.float32), cfg)
     t = ck.shape[1]
     valid = jnp.arange(t)[None, None, None, :] <= pos
     scores = jnp.where(valid, scores, -jnp.inf)

@@ -30,8 +30,28 @@ class ModelConfig:
     top_k: int = 0
     moe_capacity_factor: float = 1.25
     # SSM / hybrid
-    ssm_state: int = 0
+    ssm_state: int = 0                  # Mamba-2 d_state
     attn_every: int = 0                 # hybrid: shared attn every k blocks
+    # hybrid with per-layer types ("mamba" | "attention"): attention layers
+    # with their own weights and an MLP after every mixer
+    layer_types: Tuple[str, ...] = ()
+    # Mamba-2 widths: heads of mamba_d_head (mamba_n_heads 0: an inner
+    # width of 2 * d_model), B and C in mamba_n_groups groups, causal conv
+    # of mamba_d_conv taps, SSD prefill in chunks of mamba_chunk positions
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 64
+    mamba_n_groups: int = 1
+    mamba_d_conv: int = 4
+    mamba_conv_bias: bool = False
+    mamba_chunk: int = 256
+    # muP multipliers: embeddings scaled up, each residual branch scaled,
+    # attention scores scaled by attention_multiplier (None: 1/sqrt(hd)),
+    # logits divided by logits_scaling
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    logits_scaling: float = 1.0
+    rope: bool = True                   # False: no position embedding (NoPE)
     # audio (musicgen): codebooks summed at the embedding (frontend stub)
     n_codebooks: int = 0
     rope_theta: float = 1e4
@@ -47,6 +67,40 @@ class ModelConfig:
     @property
     def hd(self) -> int:
         return self.head_dim or self.d_model // self.n_heads
+
+    @property
+    def mamba_d_inner(self) -> int:
+        if self.mamba_n_heads:
+            return self.mamba_n_heads * self.mamba_d_head
+        return 2 * self.d_model
+
+    def hybrid_period(self) -> Tuple[str, ...]:
+        """The hybrid's repeating unit of layers, in order: the shortest
+        prefix of ``layer_types`` that repeats to the whole stack, or
+        (zamba2, no ``layer_types``) ``attn_every`` Mamba layers then the
+        shared attention block ("shared")."""
+        if not self.layer_types:
+            return ("mamba",) * self.attn_every + ("shared",)
+        lt, n = tuple(self.layer_types), self.n_layers
+        if len(lt) != n:
+            raise ValueError(f"layer_types has {len(lt)} entries for "
+                             f"{n} layers")
+        for p in range(1, n + 1):
+            if n % p == 0 and lt == lt[:p] * (n // p):
+                return lt[:p]
+        raise AssertionError("unreachable: the whole stack is a period")
+
+    def attention_layers(self) -> int:
+        """Layers that attend, each with its own KV cache: the attention
+        entries of ``layer_types``, one shared block per group for zamba2,
+        none for ssm, every layer otherwise."""
+        if self.family == "ssm":
+            return 0
+        if self.layer_types:
+            return sum(t == "attention" for t in self.layer_types)
+        if self.family == "hybrid":
+            return self.n_layers // self.attn_every
+        return self.n_layers
 
     @property
     def attn_free(self) -> bool:
@@ -70,11 +124,20 @@ class ModelConfig:
             cm = d * f + f * d + d * d
             per_layer = tm + cm + 2 * d
         if self.family == "hybrid":
+            din, g = self.mamba_d_inner, self.mamba_n_groups
+            nheads_m = din // self.mamba_d_head
+            conv = (din + 2 * g * self.ssm_state)
+            mamba = (d * (2 * din + 2 * g * self.ssm_state + nheads_m)
+                     + din * d + self.mamba_d_conv * conv + 3 * nheads_m
+                     + din + (conv if self.mamba_conv_bias else 0))
+            if self.layer_types:
+                # each layer: its own mixer and norm, an MLP and its norm
+                n_attn = self.attention_layers()
+                mixers = (n_attn * attn
+                          + (self.n_layers - n_attn) * mamba)
+                return (mixers + self.n_layers * (3 * d * f + 2 * d)
+                        + d + v * d + v * d)
             # mamba2-only layers; the SHARED block (attn + MLP) counts once
-            din = 2 * d
-            nheads_m = din // 64
-            mamba = (d * (2 * din + 2 * self.ssm_state + nheads_m)
-                     + din * d + 2 * din)
             per_layer = mamba + d
             shared = attn + 3 * d * f + 2 * d
             return self.n_layers * per_layer + shared + v * d + v * d

@@ -5,8 +5,12 @@ Families:
   dense / moe / vlm / audio — pre-norm GQA transformer (+ MoE FFN);
     vlm (chameleon): early-fusion discrete tokens, frontend stubbed to ids;
     audio (musicgen): n_codebooks embeddings summed (EnCodec frontend stub).
-  hybrid (zamba2) — mamba2 backbone with a *shared* attention block applied
-    every ``attn_every`` layers (one set of attn weights, G call sites).
+  hybrid — Mamba-2 layers and attention in a repeating period of layers
+    (:meth:`ModelConfig.hybrid_period`), scanned period by period:
+    zamba2's Mamba backbone with one *shared* attention block applied every
+    ``attn_every`` layers (one set of attn weights, G call sites), or
+    per-layer types (granite-4.0-h): attention layers with their own
+    weights, an MLP after every mixer, muP multipliers.
   ssm (rwkv6) — attention-free time-mix/channel-mix.
 
 Layers are stacked and scanned (compact HLO at 512 devices); blocks are
@@ -108,12 +112,42 @@ def _rwkv_block_fwd(p: RwkvBlockParams, cfg: ModelConfig, x,
 # ---------------------------------------------------------------------------
 
 class HybridParams(NamedTuple):
-    mamba: Any                 # stacked (G, E, ...) Mamba2Params
-    mamba_ln: jax.Array        # (G, E, d)
-    shared_ln: jax.Array       # (d,)
-    shared_attn: AttnParams    # ONE set of weights, applied G times
-    shared_ln2: jax.Array      # (d,)
-    shared_mlp: Any            # MlpParams, shared like the attention
+    """G periods stacked on the leading axis; a period holds M Mamba
+    layers and A attention layers of its own (per-layer types), or calls
+    the one shared block (zamba2)."""
+    mamba: Any                 # stacked (G, M, ...) Mamba2Params
+    mamba_ln: jax.Array        # (G, M, d)
+    shared_ln: Any = None      # (d,)
+    shared_attn: Any = None    # AttnParams: ONE set, applied G times
+    shared_ln2: Any = None     # (d,)
+    shared_mlp: Any = None     # MlpParams, shared like the attention
+    attn: Any = None           # (G, A, ...) AttnParams, per-layer types
+    attn_ln: Any = None        # (G, A, d)
+    mlp: Any = None            # (G, P, ...) MlpParams: one after every mixer
+    mlp_ln: Any = None         # (G, P, d)
+
+
+def _at(tree, g, j):
+    """Entry (g, j) of every leaf of a tree stacked (G, per period, ...):
+    one slice of each leaf, so a weight's single reader (a matmul) reads
+    it in place, with no copy of the period."""
+    def one(a):
+        zero = (jnp.int32(0),) * (a.ndim - 2)
+        return jax.lax.dynamic_slice(
+            a, (jnp.asarray(g, jnp.int32), jnp.int32(j)) + zero,
+            (1, 1) + a.shape[2:])[0, 0]
+    return jax.tree_util.tree_map(one, tree)
+
+
+def _set(tree, g, j, new):
+    """``tree`` with entry (g, j) replaced, in place (a carried buffer
+    takes a layer-sized update)."""
+    def put(a, n):
+        zero = (jnp.int32(0),) * n.ndim
+        return jax.lax.dynamic_update_slice(
+            a, n[None, None].astype(a.dtype),
+            (jnp.asarray(g, jnp.int32), jnp.int32(j)) + zero)
+    return jax.tree_util.tree_map(put, tree, new)
 
 
 # ---------------------------------------------------------------------------
@@ -123,6 +157,78 @@ class HybridParams(NamedTuple):
 class Model:
     def __init__(self, cfg: ModelConfig):
         self.cfg = cfg
+
+    # -- the hybrid's period --------------------------------------------------
+    def _hybrid_dims(self):
+        """(G periods, Mamba layers a period, attention sites a period)."""
+        cfg = self.cfg
+        period = cfg.hybrid_period()
+        g = (cfg.n_layers // len(period) if cfg.layer_types
+             else cfg.n_layers // cfg.attn_every)
+        sites = 1 if "shared" in period else period.count("attention")
+        return g, period.count("mamba"), sites
+
+    def _run_period(self, hp: HybridParams, g, x, mamba_fn, attn_fn):
+        """x through period ``g`` (traced) of the stack ``hp``:
+        ``mamba_fn(j, p, xn)`` runs the period's Mamba layer j and
+        ``attn_fn(a, p, xn)`` its attention site a on the normed input, each
+        returning the mixer's output.  Each layer's weights are sliced from
+        the stacks where they are read."""
+        from .common import rmsnorm
+        cfg = self.cfg
+        eps, r = cfg.norm_eps, cfg.residual_multiplier
+
+        def add(x, y):
+            return x + (y * r if r != 1.0 else y)
+
+        jm = ja = 0
+        for li, kind in enumerate(cfg.hybrid_period()):
+            if kind == "shared":
+                with jax.named_scope("attention"):
+                    x = x + attn_fn(0, hp.shared_attn,
+                                    rmsnorm(x, hp.shared_ln, eps))
+                with jax.named_scope("mlp"):
+                    x = x + ffn_mod.mlp(hp.shared_mlp,
+                                        rmsnorm(x, hp.shared_ln2, eps))
+                continue
+            if kind == "mamba":
+                with jax.named_scope("mamba"):
+                    x = add(x, mamba_fn(
+                        jm, _at(hp.mamba, g, jm),
+                        rmsnorm(x, _at(hp.mamba_ln, g, jm), eps)))
+                jm += 1
+            else:
+                with jax.named_scope("attention"):
+                    x = add(x, attn_fn(
+                        ja, _at(hp.attn, g, ja),
+                        rmsnorm(x, _at(hp.attn_ln, g, ja), eps)))
+                ja += 1
+            if hp.mlp is not None:
+                with jax.named_scope("mlp"):
+                    x = add(x, ffn_mod.mlp(
+                        _at(hp.mlp, g, li),
+                        rmsnorm(x, _at(hp.mlp_ln, g, li), eps)))
+        return x
+
+    def _head(self, params, x):
+        """Next-token logits of the final normed hidden states x (..., d)."""
+        logits = jnp.einsum("...d,dv->...v", x, params["head"])
+        if self.cfg.logits_scaling != 1.0:
+            logits = logits / self.cfg.logits_scaling
+        return logits
+
+    def ssm_layers(self) -> int:
+        """Mamba layers (0 without them)."""
+        if self.cfg.family != "hybrid":
+            return 0
+        g, m, _ = self._hybrid_dims()
+        return g * m
+
+    def ssm_state_bytes(self) -> int:
+        """Bytes of one slot's recurrent state over every Mamba layer."""
+        if not self.ssm_layers():
+            return 0
+        return self.ssm_layers() * mamba_mod.state_bytes(self.cfg)
 
     # -- init ---------------------------------------------------------------
     def init_params(self, key) -> Dict:
@@ -145,19 +251,30 @@ class Model:
             params["blocks"] = jax.vmap(
                 lambda k: _init_rwkv_block(k, cfg))(keys)
         elif cfg.family == "hybrid":
-            g = cfg.n_layers // cfg.attn_every
-            keys = jax.random.split(kB, g * cfg.attn_every).reshape(
-                g, cfg.attn_every, 2)
-            mamba = jax.vmap(jax.vmap(
-                lambda k: mamba_mod.init_mamba2(k, cfg)))(keys)
+            g, m, a = self._hybrid_dims()
+            d = cfg.d_model
+
+            def stack(key, n, init):
+                keys = jax.random.split(key, g * n).reshape(g, n, 2)
+                return jax.vmap(jax.vmap(lambda k: init(k, cfg)))(keys)
+            mamba = stack(kB, m, mamba_mod.init_mamba2)
             kF1, kF2 = jax.random.split(kF)
-            params["blocks"] = HybridParams(
-                mamba=mamba,
-                mamba_ln=jnp.ones((g, cfg.attn_every, cfg.d_model), cfg.dtype),
-                shared_ln=jnp.ones((cfg.d_model,), cfg.dtype),
-                shared_attn=attn_mod.init_attn(kF1, cfg),
-                shared_ln2=jnp.ones((cfg.d_model,), cfg.dtype),
-                shared_mlp=ffn_mod.init_mlp(kF2, cfg))
+            ones = lambda *s: jnp.ones(s, cfg.dtype)  # noqa: E731
+            if cfg.layer_types:
+                p = len(cfg.hybrid_period())
+                params["blocks"] = HybridParams(
+                    mamba=mamba, mamba_ln=ones(g, m, d),
+                    attn=stack(kF1, a, attn_mod.init_attn),
+                    attn_ln=ones(g, a, d),
+                    mlp=stack(kF2, p, ffn_mod.init_mlp),
+                    mlp_ln=ones(g, p, d))
+            else:
+                params["blocks"] = HybridParams(
+                    mamba=mamba, mamba_ln=ones(g, m, d),
+                    shared_ln=ones(d),
+                    shared_attn=attn_mod.init_attn(kF1, cfg),
+                    shared_ln2=ones(d),
+                    shared_mlp=ffn_mod.init_mlp(kF2, cfg))
         else:
             keys = jax.random.split(kB, cfg.n_layers)
             params["blocks"] = jax.vmap(lambda k: _init_block(k, cfg))(keys)
@@ -170,7 +287,10 @@ class Model:
             # tokens: (b, s, K) — summed codebook embeddings (EnCodec stub)
             return sum(jnp.take(params["embed"][i], tokens[..., i], axis=0)
                        for i in range(cfg.n_codebooks))
-        return jnp.take(params["embed"], tokens, axis=0)
+        x = jnp.take(params["embed"], tokens, axis=0)
+        if cfg.embedding_multiplier != 1.0:
+            x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
+        return x
 
     # -- forward (train / scoring) -------------------------------------------
     def forward(self, params, tokens):
@@ -188,25 +308,15 @@ class Model:
             x, _ = jax.lax.scan(fn, x, params["blocks"])
         elif cfg.family == "hybrid":
             hp: HybridParams = params["blocks"]
-            from .common import rmsnorm
 
-            def group(carry, layer):
-                x = carry
-                mam, lns = layer
-
-                def inner(c, l):
-                    mp, ln = l
-                    y, _ = mamba_mod.forward(mp, cfg, rmsnorm(c, ln,
-                                                              cfg.norm_eps))
-                    return c + y, None
-                x, _ = jax.lax.scan(inner, x, (mam, lns))
-                xa = rmsnorm(x, hp.shared_ln, cfg.norm_eps)
-                x = x + attn_mod.attention(hp.shared_attn, cfg, xa, positions)
-                xm = rmsnorm(x, hp.shared_ln2, cfg.norm_eps)
-                x = x + ffn_mod.mlp(hp.shared_mlp, xm)
-                return x, None
+            def group(x, g):
+                return self._run_period(
+                    hp, g, x,
+                    lambda j, mp, xn: mamba_mod.forward(mp, cfg, xn)[0],
+                    lambda a, ap, xn: attn_mod.attention(ap, cfg, xn,
+                                                         positions)), None
             fn = jax.checkpoint(group) if cfg.remat else group
-            x, _ = jax.lax.scan(fn, x, (hp.mamba, hp.mamba_ln))
+            x, _ = jax.lax.scan(fn, x, jnp.arange(self._hybrid_dims()[0]))
         else:
             aux0 = jnp.zeros((), jnp.float32)
 
@@ -220,8 +330,7 @@ class Model:
 
         from .common import rmsnorm
         x = rmsnorm(x, params["ln_f"], cfg.norm_eps)
-        logits = jnp.einsum("bsd,dv->bsv", x, params["head"])
-        return logits
+        return self._head(params, x)
 
     def loss(self, params, batch) -> jax.Array:
         logits = self.forward(params, batch["tokens"])
@@ -239,15 +348,11 @@ class Model:
                 lambda l: jnp.broadcast_to(l, (cfg.n_layers,) + l.shape),
                 states)
         if cfg.family == "hybrid":
-            g = cfg.n_layers // cfg.attn_every
-            ms = mamba_mod.init_state(cfg, batch)
-            mstack = jax.tree_util.tree_map(
-                lambda l: jnp.broadcast_to(
-                    l, (g, cfg.attn_every) + l.shape), ms)
             kv = attn_mod.init_cache(cfg, batch, max_seq)
             kvstack = jax.tree_util.tree_map(
-                lambda l: jnp.broadcast_to(l, (g,) + l.shape), kv)
-            return {"mamba": mstack, "kv": kvstack}
+                lambda l: jnp.broadcast_to(
+                    l, (cfg.attention_layers(),) + l.shape), kv)
+            return {"mamba": self.init_prefill_state(batch), "kv": kvstack}
         kv = attn_mod.init_cache(cfg, batch, max_seq)
         return jax.tree_util.tree_map(
             lambda l: jnp.broadcast_to(l, (cfg.n_layers,) + l.shape), kv)
@@ -266,17 +371,12 @@ class Model:
         if cfg.family == "ssm":
             return self.init_cache(batch, max_seq)
         pool = attn_mod.init_paged_kv(cfg, n_blocks, block_size)
+        kvstack = jax.tree_util.tree_map(
+            lambda l: jnp.broadcast_to(l, (cfg.attention_layers(),)
+                                       + l.shape), pool)
         if cfg.family == "hybrid":
-            g = cfg.n_layers // cfg.attn_every
-            ms = mamba_mod.init_state(cfg, batch)
-            mstack = jax.tree_util.tree_map(
-                lambda l: jnp.broadcast_to(
-                    l, (g, cfg.attn_every) + l.shape), ms)
-            kvstack = jax.tree_util.tree_map(
-                lambda l: jnp.broadcast_to(l, (g,) + l.shape), pool)
-            return {"mamba": mstack, "kv": kvstack}
-        return jax.tree_util.tree_map(
-            lambda l: jnp.broadcast_to(l, (cfg.n_layers,) + l.shape), pool)
+            return {"mamba": self.init_prefill_state(batch), "kv": kvstack}
+        return kvstack
 
     def split_paged_cache(self, cache):
         """(kv pools, slot-indexed recurrent state) — either may be None."""
@@ -302,11 +402,10 @@ class Model:
         if cfg.family == "ssm":
             return self.init_cache(batch, 1)
         if cfg.family == "hybrid":
-            g = cfg.n_layers // cfg.attn_every
+            g, m, _ = self._hybrid_dims()
             ms = mamba_mod.init_state(cfg, batch)
             return jax.tree_util.tree_map(
-                lambda l: jnp.broadcast_to(
-                    l, (g, cfg.attn_every) + l.shape), ms)
+                lambda l: jnp.broadcast_to(l, (g, m) + l.shape), ms)
         return None
 
     def prefill(self, params, tokens, cache, start: int = 0, lengths=None,
@@ -348,32 +447,35 @@ class Model:
             new_cache = new_states
         elif cfg.family == "hybrid":
             hp: HybridParams = params["blocks"]
+            g, _, sites = self._hybrid_dims()
+            # the dense KV cache (sites, b, max_seq, ...) as (G, sites a
+            # period, ...) scanned beside the periods
+            kv_g = jax.tree_util.tree_map(
+                lambda a: a.reshape((g, sites) + a.shape[1:]), cache["kv"])
 
-            def group(carry, inp):
-                x = carry
-                (mam, lns), mstates, kv = inp
+            def group(carry, gi):
+                x, st, kv = carry
 
-                def inner(c, l):
-                    (mp, ln), st = l
-                    y, nst = mamba_mod.forward(
-                        mp, cfg, rmsnorm(c, ln, cfg.norm_eps), st,
-                        lengths=lengths)
-                    return c + y, nst
-                x, new_mst = jax.lax.scan(inner, x, ((mam, lns), mstates))
-                xa = rmsnorm(x, hp.shared_ln, cfg.norm_eps)
-                if attend_cache:
-                    y, new_kv = attn_mod.attention_prefill_cached(
-                        hp.shared_attn, cfg, xa, kv, start)
-                else:
-                    y, new_kv = attn_mod.attention_prefill(
-                        hp.shared_attn, cfg, xa, kv, start)
-                x = x + y
-                xm = rmsnorm(x, hp.shared_ln2, cfg.norm_eps)
-                x = x + ffn_mod.mlp(hp.shared_mlp, xm)
-                return x, (new_mst, new_kv)
-            x, (new_mst, new_kv) = jax.lax.scan(
-                group, x, ((hp.mamba, hp.mamba_ln), cache["mamba"],
-                           cache["kv"]))
+                def mamba_fn(j, mp, xn):
+                    nonlocal st
+                    y, nst = mamba_mod.forward(mp, cfg, xn, _at(st, gi, j),
+                                               lengths=lengths)
+                    st = _set(st, gi, j, nst)
+                    return y
+
+                def attn_fn(a, ap, xn):
+                    nonlocal kv
+                    run = (attn_mod.attention_prefill_cached if attend_cache
+                           else attn_mod.attention_prefill)
+                    y, nkv = run(ap, cfg, xn, _at(kv, gi, a), start)
+                    kv = _set(kv, gi, a, nkv)
+                    return y
+                x = self._run_period(hp, gi, x, mamba_fn, attn_fn)
+                return (x, st, kv), None
+            (x, new_mst, new_kv), _ = jax.lax.scan(
+                group, (x, cache["mamba"], kv_g), jnp.arange(g))
+            new_kv = jax.tree_util.tree_map(
+                lambda a: a.reshape((g * sites,) + a.shape[2:]), new_kv)
             new_cache = {"mamba": new_mst, "kv": new_kv}
         else:
             def body(carry, layer_and_cache):
@@ -404,8 +506,7 @@ class Model:
             idx = jnp.clip(jnp.asarray(lengths, jnp.int32) - 1, 0, s - 1)
             x_last = jnp.take_along_axis(
                 x, idx[:, None, None], axis=1)[:, 0]
-        logits = jnp.einsum("bd,dv->bv", x_last, params["head"])
-        return logits, new_cache
+        return self._head(params, x_last), new_cache
 
     def prefill_paged(self, params, tokens, kv, bt_row, state, start,
                       lengths, *, first: bool):
@@ -415,8 +516,9 @@ class Model:
         (:meth:`split_paged_cache`; None for ssm); bt_row: the slot's
         (max_blocks,) block-table row; state: batch-1 recurrent staging
         state (:meth:`init_prefill_state`; None for attention-only
-        families); start: chunk offset (traced ok when ``not first``);
-        lengths: (1,) real token count WITHIN this chunk.  Returns
+        families, and zeros for a hybrid's fresh prompt); start: chunk
+        offset (traced ok when ``not first``); lengths: (1,) real token
+        count WITHIN this chunk.  Returns
         (last_logits, kv, state).
 
         ``first`` (static) is the chunk-0 form: attention runs within
@@ -436,29 +538,31 @@ class Model:
 
         if cfg.family == "hybrid":
             hp: HybridParams = params["blocks"]
+            _, _, sites = self._hybrid_dims()
+            if state is None:                 # a fresh prompt
+                state = self.init_prefill_state(b)
 
-            def group(carry, inp):
-                x, ck, cv, gi = carry
-                (mam, lns), mstates = inp
+            def group(carry, g):
+                x, ck, cv, st = carry
 
-                def inner(c, l):
-                    (mp, ln), st = l
-                    y, nst = mamba_mod.forward(
-                        mp, cfg, rmsnorm(c, ln, cfg.norm_eps), st,
-                        lengths=lengths)
-                    return c + y, nst
-                x, new_mst = jax.lax.scan(inner, x, ((mam, lns), mstates))
-                xa = rmsnorm(x, hp.shared_ln, cfg.norm_eps)
-                y, ck, cv = attn_mod.paged_attention_prefill(
-                    hp.shared_attn, cfg, xa, ck, cv, gi, bt_row, start,
-                    first=first)
-                x = x + y
-                xm = rmsnorm(x, hp.shared_ln2, cfg.norm_eps)
-                x = x + ffn_mod.mlp(hp.shared_mlp, xm)
-                return (x, ck, cv, gi + 1), new_mst
-            (x, ck, cv, _), new_state = jax.lax.scan(
-                group, (x, kv.k, kv.v, jnp.int32(0)),
-                ((hp.mamba, hp.mamba_ln), state))
+                def mamba_fn(j, mp, xn):
+                    nonlocal st
+                    y, nst = mamba_mod.forward(mp, cfg, xn, _at(st, g, j),
+                                               lengths=lengths)
+                    st = _set(st, g, j, nst)
+                    return y
+
+                def attn_fn(a, ap, xn):
+                    nonlocal ck, cv
+                    y, ck, cv = attn_mod.paged_attention_prefill(
+                        ap, cfg, xn, ck, cv, g * sites + a, bt_row, start,
+                        first=first)
+                    return y
+                x = self._run_period(hp, g, x, mamba_fn, attn_fn)
+                return (x, ck, cv, st), None
+            (x, ck, cv, new_state), _ = jax.lax.scan(
+                group, (x, kv.k, kv.v, state),
+                jnp.arange(self._hybrid_dims()[0]))
         else:
             def body(carry, layer):
                 x, ck, cv, li = carry
@@ -484,17 +588,20 @@ class Model:
             idx = jnp.clip(jnp.asarray(lengths, jnp.int32) - 1, 0, s - 1)
             x_last = jnp.take_along_axis(x, idx[:, None, None],
                                          axis=1)[:, 0]
-            logits = jnp.einsum("bd,dv->bv", x_last, params["head"])
+            logits = self._head(params, x_last)
         return logits, KVCache(ck, cv), new_state
 
     def gather_paged_view(self, cache, block_tables):
         """The per-slot logical (vk, vv) view of a paged cache's pools —
         gathered once per decode chunk (None for the ssm family, which has
         no KV).  See :func:`repro.models.attention.gather_paged_view`."""
-        if self.cfg.family == "ssm":
+        cfg = self.cfg
+        if cfg.family == "ssm":
             return None
         kv, _ = self.split_paged_cache(cache)
-        return attn_mod.gather_paged_view(kv.k, kv.v, block_tables)
+        vk, vv = attn_mod.gather_paged_view(kv.k, kv.v, block_tables)
+        heads = vk.shape[:3] + (cfg.n_kv_heads, cfg.hd)
+        return vk.reshape(heads), vv.reshape(heads)
 
     def decode_step(self, params, token, cache, pos, block_tables=None,
                     kv_view=None, paged_kernel: bool = False):
@@ -533,50 +640,50 @@ class Model:
             x, new_cache = jax.lax.scan(body, x, (params["blocks"], cache))
         elif cfg.family == "hybrid":
             hp: HybridParams = params["blocks"]
-            # KV caches ride in the scan CARRY with token-sized in-place
-            # updates (attention_decode_inplace); small mamba states stay
-            # as scanned xs/ys.
-            ck0, cv0 = cache["kv"].k, cache["kv"].v   # (G, b, s, kv, hd)
+            _, _, sites = self._hybrid_dims()
+            # the KV caches and the Mamba state ride in the scan CARRY with
+            # token- and layer-sized in-place updates, so neither is copied
+            ck0, cv0 = cache["kv"].k, cache["kv"].v   # (sites, ...)
             # the view (when given) rides in the carry NEXT TO the pools —
             # attention reads/updates the view, the pool gets the mirrored
             # token write; without a view the carry keeps its dense shape
             kv0 = kv_view if kv_view is not None else ()
 
-            def group(carry, inp):
-                (x, ck, cv, gi), view = carry[:4], carry[4:]
-                (mam, lns), mstates = inp
+            def group(carry, gi):
+                (x, ck, cv, st), view = carry[:4], carry[4:]
 
-                def inner(c, l):
-                    (mp, ln), st = l
-                    y, nst = mamba_mod.decode_step(
-                        mp, cfg, rmsnorm(c, ln, cfg.norm_eps), st)
-                    return c + y, nst
-                x, new_mst = jax.lax.scan(inner, x, ((mam, lns), mstates))
-                xa = rmsnorm(x, hp.shared_ln, cfg.norm_eps)
-                if kv_view is not None:
-                    y, ck, cv, vk, vv = attn_mod.paged_attention_decode_view(
-                        hp.shared_attn, cfg, xa, ck, cv, view[0], view[1],
-                        gi, pos, block_tables)
-                    view = (vk, vv)
-                elif block_tables is not None and paged_kernel:
-                    y, ck, cv = attn_mod.paged_attention_decode_kernel(
-                        hp.shared_attn, cfg, xa, ck, cv, gi, pos,
-                        block_tables)
-                elif block_tables is not None:
-                    y, ck, cv = attn_mod.paged_attention_decode_inplace(
-                        hp.shared_attn, cfg, xa, ck, cv, gi, pos,
-                        block_tables)
-                else:
-                    y, ck, cv = attn_mod.attention_decode_inplace(
-                        hp.shared_attn, cfg, xa, ck, cv, gi, pos)
-                x = x + y
-                xm = rmsnorm(x, hp.shared_ln2, cfg.norm_eps)
-                x = x + ffn_mod.mlp(hp.shared_mlp, xm)
-                return (x, ck, cv, gi + 1) + view, new_mst
-            out_carry, new_mst = jax.lax.scan(
-                group, (x, ck0, cv0, jnp.int32(0)) + tuple(kv0),
-                ((hp.mamba, hp.mamba_ln), cache["mamba"]))
-            x, ck, cv = out_carry[0], out_carry[1], out_carry[2]
+                def mamba_fn(j, mp, xn):
+                    nonlocal st
+                    y, nst = mamba_mod.decode_step(mp, cfg, xn,
+                                                   _at(st, gi, j))
+                    st = _set(st, gi, j, nst)
+                    return y
+
+                def attn_fn(a, ap, xn):
+                    nonlocal ck, cv, view
+                    li = gi * sites + a
+                    if kv_view is not None:
+                        y, ck, cv, vk, vv = (
+                            attn_mod.paged_attention_decode_view(
+                                ap, cfg, xn, ck, cv, view[0], view[1], li,
+                                pos, block_tables))
+                        view = (vk, vv)
+                    elif block_tables is not None and paged_kernel:
+                        y, ck, cv = attn_mod.paged_attention_decode_kernel(
+                            ap, cfg, xn, ck, cv, li, pos, block_tables)
+                    elif block_tables is not None:
+                        y, ck, cv = attn_mod.paged_attention_decode_inplace(
+                            ap, cfg, xn, ck, cv, li, pos, block_tables)
+                    else:
+                        y, ck, cv = attn_mod.attention_decode_inplace(
+                            ap, cfg, xn, ck, cv, li, pos)
+                    return y
+                x = self._run_period(hp, gi, x, mamba_fn, attn_fn)
+                return (x, ck, cv, st) + view, None
+            out_carry, _ = jax.lax.scan(
+                group, (x, ck0, cv0, cache["mamba"]) + tuple(kv0),
+                jnp.arange(self._hybrid_dims()[0]))
+            x, ck, cv, new_mst = out_carry[:4]
             new_cache = {"mamba": new_mst, "kv": KVCache(ck, cv)}
             if kv_view is not None:
                 new_view = out_carry[4:6]
@@ -623,7 +730,7 @@ class Model:
 
         with jax.named_scope("head"):
             x = rmsnorm(x, params["ln_f"], cfg.norm_eps)
-            logits = jnp.einsum("bd,dv->bv", x[:, -1], params["head"])
+            logits = self._head(params, x[:, -1])
         if kv_view is not None:
             return logits, new_cache, new_view
         return logits, new_cache

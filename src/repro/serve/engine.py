@@ -1036,10 +1036,18 @@ class ContinuousEngine(_EngineBase):
                                if not s.free)
             attn = decode_attention(self.model, self.cache,
                                     self.block_tables)
+            ssm = {"ssm": "step"} if self.model.ssm_layers() else {}
+            if ssm and obs.recording():
+                # the lanes whose recurrent state the chunk carries on
+                obs.instant("serve.ssm_state",
+                            slots=sum(1 for s in self.sched.slots
+                                      if not s.free and not s.prefilling),
+                            bytes_per_slot=self.model.ssm_state_bytes(),
+                            layers=self.model.ssm_layers())
             t0 = time.perf_counter()
             try:
                 with obs.span("serve.decode_chunk", chunk=self.chunk,
-                              req_ids=req_ids, attn=attn):
+                              req_ids=req_ids, attn=attn, **ssm):
                     (self.cache, self.tokens, self.pos, self.keys, toks,
                      bad) = self._call_chunk(
                         (self.params, self.cache, self.tokens, self.pos,
@@ -1265,7 +1273,9 @@ class ContinuousEngine(_EngineBase):
         take = min(plen - start, self.buckets[-1])
         bucket = pick_bucket(take, self.buckets)
         with obs.span("serve.prefill_chunk", slot=slot, req_id=rid,
-                      bucket=bucket, start=start):
+                      bucket=bucket, start=start,
+                      **({"ssm": "chunked"} if self.model.ssm_layers()
+                         else {})):
             if start == 0:
                 self._begin_admit(slot)
             if not self._prefill_advance_inner(slot, r, plen, start, take,
@@ -1356,9 +1366,15 @@ class ContinuousEngine(_EngineBase):
             return True
         if self.kv_layout == "paged":
             if staging is not None:           # recurrent state -> its slot
-                kv, slot_state = self.model.split_paged_cache(self.cache)
-                slot_state = self._insert(slot_state, staging, slot)
-                self.cache = self.model.merge_paged_cache(kv, slot_state)
+                # the prefilled state, begun from zeros at admission,
+                # replaces the lane's whole state in every recurrent layer
+                with obs.span("serve.state_install", slot=slot,
+                              req_id=rid):
+                    kv, slot_state = self.model.split_paged_cache(
+                        self.cache)
+                    slot_state = self._insert(slot_state, staging, slot)
+                    self.cache = self.model.merge_paged_cache(kv,
+                                                              slot_state)
         else:
             self.cache = self._insert(self.cache, staging, slot)
 

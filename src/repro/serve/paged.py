@@ -165,13 +165,10 @@ def dtype_bytes(dtype) -> int:
 
 
 def _kv_layers(cfg) -> int:
-    """KV-carrying layers: none for ssm, one shared block per group for
-    hybrid, every layer otherwise."""
-    if cfg.family == "ssm":
-        return 0
-    if cfg.family == "hybrid":
-        return cfg.n_layers // cfg.attn_every
-    return cfg.n_layers
+    """KV-carrying layers (:meth:`ModelConfig.attention_layers`): none for
+    ssm, the attention entries of a hybrid's ``layer_types`` (one shared
+    block per group without them), every layer otherwise."""
+    return cfg.attention_layers()
 
 
 def dense_kv_bytes(cfg, slots: int, max_seq: int) -> int:

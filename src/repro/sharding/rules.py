@@ -126,12 +126,11 @@ def params_specs(params, mesh: Mesh, cfg) -> object:
         p = _path_str(path)
         stacked = 0
         if p.startswith("blocks"):
-            # scan-stacked: 1 leading dim; hybrid mamba stack has 2 (G, E)
+            # scan-stacked: 1 leading dim; the hybrid's per-period stacks
+            # have 2 (G, per period), its shared block none
             stacked = 1
-            if "mamba" in p and "shared" not in p:
-                stacked = 2
-            if "shared" in p:
-                stacked = 0
+            if cfg.family == "hybrid":
+                stacked = 0 if "shared" in p else 2
         return param_spec(leaf, p, mesh, fsdp=cfg.fsdp, stacked_dims=stacked)
 
     return jax.tree_util.tree_map_with_path(assign, params)

@@ -19,7 +19,9 @@ OPS = {"n": 8192, "mm": (128, 256, 384), "rows": 64, "d": 256}
 KERNELS = {"tokens": 128, "d": 256, "ff": 512, "heads": 4, "kv_heads": 2,
            "head_dim": 128}
 PAGED = ({"name": "gqa4", "layers": 3, "pages": 24, "kv_heads": 8,
-          "max_seq": 160},)
+          "max_seq": 160},
+         {"name": "packed64", "layers": 2, "pages": 40, "kv_heads": 8,
+          "max_seq": 160, "slots": 16, "head_dim": 64, "scale": 1 / 64})
 
 
 def _chip_smoke():

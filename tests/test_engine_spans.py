@@ -166,6 +166,66 @@ def test_lifecycle_spans_tile_each_request(tiny):
                       for a in kv)
 
 
+@pytest.fixture(scope="module")
+def hybrid_tiny():
+    """Mamba-2 layers beside attention layers of their own (granite-4.0-h's
+    kinds at CPU widths)."""
+    cfg = ModelConfig(name="spans-h", family="hybrid", n_layers=4,
+                      d_model=64, n_heads=4, n_kv_heads=2, d_ff=128,
+                      vocab=128, ssm_state=8,
+                      layer_types=("mamba", "attention") * 2,
+                      mamba_n_heads=4, mamba_d_head=16, mamba_conv_bias=True,
+                      mamba_chunk=8, dtype="float32", remat=False,
+                      max_seq=128)
+    model = Model(cfg)
+    return cfg, model, model.init_params(jax.random.PRNGKey(0))
+
+
+def test_state_install_span_and_ssm_state_instant(hybrid_tiny):
+    """Each admission installs its prefilled state in one
+    ``serve.state_install`` span inside ``serve.finish_admit``; each
+    decode chunk is preceded by a ``serve.ssm_state`` instant naming the
+    lanes holding live state; prefill and decode spans name the SSM path."""
+    cfg, model, _ = hybrid_tiny
+    eng = _engine(hybrid_tiny)
+    reqs = _requests(cfg)
+    eng.run(_requests(cfg))                  # compile outside the check
+    obs.enable()
+    obs.clear_trace()
+    _serve(eng, reqs)
+    sp = obs.spans()
+    installs = [s for s in sp if s.name == "serve.state_install"]
+    finishes = [s for s in sp if s.name == "serve.finish_admit"]
+    assert len(installs) == len(reqs) == len(finishes)
+    for s in installs:
+        assert any(f.t0_ns <= s.t0_ns and s.t1_ns <= f.t1_ns
+                   and f.args["slot"] == s.args["slot"] for f in finishes)
+    states = [s for s in sp if s.name == "serve.ssm_state"]
+    chunks = [s for s in sp if s.name == "serve.decode_chunk"]
+    assert len(states) == len(chunks) > 0
+    assert all(s.ph == "i" and 1 <= s.args["slots"] <= 2
+               and s.args["layers"] == 2
+               and s.args["bytes_per_slot"] == model.ssm_state_bytes()
+               for s in states)
+    # two layers of (conv tail 3 x (64 + 16) + SSM state 4 x 16 x 8) f32
+    assert model.ssm_state_bytes() == 2 * (3 * 80 * 4 + 4 * 16 * 8 * 4)
+    assert all(c.args["ssm"] == "step" for c in chunks)
+    assert all(s.args["ssm"] == "chunked" for s in sp
+               if s.name == "serve.prefill_chunk")
+
+
+def test_no_ssm_records_without_recurrent_layers(tiny):
+    eng = _engine(tiny)
+    eng.run(_requests(tiny[0]))
+    obs.enable()
+    obs.clear_trace()
+    _serve(eng, _requests(tiny[0]))
+    sp = obs.spans()
+    assert not [s for s in sp if s.name in ("serve.state_install",
+                                            "serve.ssm_state")]
+    assert all("ssm" not in (s.args or {}) for s in sp)
+
+
 def test_written_blocks_counts_pages_holding_a_position():
     pool = BlockPool(16, 16)
     sched = Scheduler(2, pool=pool)

@@ -86,6 +86,30 @@ class TestBlockPool:
         ssm = tiny_cfg(family="ssm", name="paged-ssm")
         assert dense_kv_bytes(ssm, 4, 64) == 0   # no KV cache at all
 
+    def test_per_layer_types_price_only_the_attention_layers(self):
+        """granite-4.0-h-micro: 4 attention layers in 40 hold KV pages,
+        in a pool of 16384 pages of 16 positions, 8 heads of 64 lanes."""
+        from repro.serve.paged import _kv_layers
+        types = (["mamba"] * 5 + ["attention"] + ["mamba"] * 4) * 4
+        cfg = ModelConfig(name="granite-t", family="hybrid", n_layers=40,
+                          d_model=2048, n_heads=32, n_kv_heads=8,
+                          d_ff=8192, vocab=100352, ssm_state=128,
+                          layer_types=tuple(types), mamba_n_heads=64,
+                          mamba_conv_bias=True)
+        assert _kv_layers(cfg) == 4 == cfg.attention_layers()
+        assert cfg.hybrid_period() == tuple(types[:10])
+        assert paged_kv_bytes(cfg, 16384, 16) == (2 * 4 * 16384 * 16 * 8
+                                                  * 64 * 2)
+        # each layer counted once: 36 Mamba mixers (in-proj, conv with
+        # bias, A/dt/D, gated norm, out-proj), 4 attention mixers, 40
+        # MLPs and 80 norms, the final norm, embedding and (untied) head
+        mamba = (2048 * (2 * 4096 + 2 * 128 + 64) + 4096 * 2048
+                 + 4 * 4352 + 4352 + 3 * 64 + 4096)
+        attn = 2048 * 2048 * 2 + 2 * 2048 * 512
+        assert cfg.param_count() == (36 * mamba + 4 * attn
+                                     + 40 * (3 * 2048 * 8192 + 2 * 2048)
+                                     + 2048 + 2 * 100352 * 2048)
+
 
 # ---------------------------------------------------------------------------
 # paged engine == dense oracle, all families

@@ -71,6 +71,58 @@ def test_kernel_float32_pool_and_block_boundaries():
                                atol=1e-5, rtol=1e-5)
 
 
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_kernel_reads_heads_of_64_packed_two_a_row(dtype):
+    """granite-4.0-h's heads of 64 lanes, two to a 128-lane pool row, with
+    its score scale of 1/64 (not 1/sqrt(64)): each head is read from its
+    half of the row."""
+    nh, nkv, hd, layers, n_blocks, max_blocks = 8, 4, 64, 2, 48, 6
+    cfg = types.SimpleNamespace(n_heads=nh, n_kv_heads=nkv, hd=hd,
+                                attention_multiplier=1 / 64)
+    kk, kv, kq = jax.random.split(jax.random.PRNGKey(11), 3)
+    shape = (layers, n_blocks, BS, nkv, hd)
+    kp = jax.random.normal(kk, shape, jnp.float32).astype(dtype)
+    vp = jax.random.normal(kv, shape, jnp.float32).astype(dtype)
+    q = jax.random.normal(kq, (5, nh, hd), jnp.float32).astype(dtype)
+    bt = jax.random.permutation(jax.random.PRNGKey(12), n_blocks)[
+        :5 * max_blocks].reshape(5, max_blocks).astype(jnp.int32)
+    lengths = jnp.asarray([1, 16, 17, 90, max_blocks * BS], jnp.int32)
+    packed = lambda a: a.reshape(a.shape[:3] + (nkv // 2, 2 * hd))  # noqa
+    out = paged_decode_attention(q, packed(kp), packed(vp), jnp.int32(1),
+                                 lengths, bt, scale=1 / 64)
+    vk, vv = attention.gather_paged_view(kp, vp, bt)
+    ref = attention._attend_token(cfg, q[:, None], vk[1], vv[1],
+                                  lengths - 1, True, jnp.float32,
+                                  jnp.eye(nh * hd, dtype=jnp.float32))
+    tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(ref).reshape(5, nh, hd),
+                               atol=tol, rtol=tol)
+
+
+def test_heads_of_128_take_the_kernel_as_it_was():
+    """The query goes to the kernel as (b, nkv, group, hd) and its output
+    comes back so: no lanes are placed or read back around the kernel, for
+    a row of one head or of two (the kernel slices a row's heads)."""
+    kp, vp = _pools(jax.random.PRNGKey(13), 1, 16, 2)
+    q = jnp.zeros((3, 8, HD), jnp.bfloat16)
+    bt = jnp.arange(12, dtype=jnp.int32).reshape(3, 4)
+    args = (q, kp, vp, jnp.int32(0), jnp.ones((3,), jnp.int32), bt)
+    jaxpr = jax.make_jaxpr(paged_decode_attention)(*args)
+    inner = jaxpr.eqns[0].params["jaxpr"].jaxpr
+    prims = {e.primitive.name for e in inner.eqns}
+    assert not prims & {"pad", "concatenate", "slice"}, prims
+    # heads of 64, two to a row (float32: one row, read unpaired)
+    packed = jax.make_jaxpr(paged_decode_attention)(
+        q[..., :64].astype(jnp.float32),
+        kp.reshape(1, 16, BS, 1, 2 * HD)[..., :HD].astype(jnp.float32),
+        vp.reshape(1, 16, BS, 1, 2 * HD)[..., :HD].astype(jnp.float32),
+        *args[3:])
+    prims = {e.primitive.name
+             for e in packed.eqns[0].params["jaxpr"].jaxpr.eqns}
+    assert not prims & {"pad", "concatenate", "slice"}, prims
+
+
 def _tiny(**kw):
     base = dict(name="pdk-t", family="dense", n_layers=2, d_model=64,
                 n_heads=4, n_kv_heads=2, d_ff=128, vocab=128,
@@ -121,13 +173,28 @@ def _admitted_engine(model, params):
     return eng
 
 
-@pytest.mark.parametrize("family", ["dense", "hybrid"])
+def _granite_tiny():
+    """granite-4.0-h's layer kinds and multipliers at CPU widths, with
+    heads of 64 packed two to a pool row."""
+    return _tiny(family="hybrid", n_layers=4, d_model=128, n_heads=4,
+                 n_kv_heads=2, head_dim=64, ssm_state=16,
+                 layer_types=("mamba", "attention") * 2, mamba_n_heads=4,
+                 mamba_d_head=32, mamba_conv_bias=True, mamba_chunk=16,
+                 embedding_multiplier=12.0, residual_multiplier=0.22,
+                 attention_multiplier=1 / 64, logits_scaling=8.0,
+                 rope=False)
+
+
+@pytest.mark.parametrize("family", ["dense", "hybrid", "granite"])
 def test_chunk_logits_match_the_view_path(family, monkeypatch):
     """The fused chunk on the kernel path (predicate patched) against the
     view path from the same state: the same tokens and pools, and each
     step's logits allclose."""
     if family == "dense":
         cfg = _tiny()
+    elif family == "granite":
+        cfg = _granite_tiny()
+        assert attention.pool_rows(cfg) == (1, 128)
     else:
         from repro.configs import smoke_config
         cfg = smoke_config("zamba2-2.7b")

@@ -22,6 +22,7 @@ from repro.kernels.matmul import matmul
 from repro.kernels.paged_decode import paged_decode_attention
 from repro.kernels.rmsnorm import rmsnorm
 from repro.models import attention
+from repro.models.common import ModelConfig
 from repro.models.transformer import Model
 from repro.serve.engine import ContinuousEngine
 
@@ -152,6 +153,57 @@ def test_paged_decode_kernel_compiles(one_chip, layers, n_blocks, nkv,
         _sds(one_chip, (8, max_seq // 16), i32))
     # the pools go to the kernel as they are: no copy of a pool
     assert "copy(%k" not in text and "copy(%v" not in text
+
+
+def test_paged_decode_kernel_compiles_for_heads_of_64(one_chip):
+    """granite-4.0-h-micro's pool: 4 attention layers, 16384 pages, 8 kv
+    heads of 64 lanes packed two to a row, scores scaled by 1/64."""
+    bf, i32 = jnp.bfloat16, jnp.int32
+    pool = _sds(one_chip, (4, 16384, 16, 4, 128), bf)
+    assert attention.paged_kernel_engages(pool)
+    text = _kernel_hlo(
+        lambda q, k, v, li, n, bt: paged_decode_attention(
+            q, k, v, li, n, bt, scale=1 / 64),
+        _sds(one_chip, (32, 32, 64), bf), pool, pool,
+        _sds(one_chip, (), i32), _sds(one_chip, (32,), i32),
+        _sds(one_chip, (32, 8192 // 16), i32))
+    assert "copy(%k" not in text and "copy(%v" not in text
+
+
+def test_hybrid_decode_chunk_reads_each_layer_in_place_for_v5e(one_chip):
+    """One period (10 layers) of granite-4.0-h-micro at its widths, 32
+    slots of 8192: the chunk runs the Mosaic kernel, gathers no view, and
+    copies neither a period's weights nor the Mamba state (each layer's
+    slice is read where it is used; the state is updated in place)."""
+    types_ = ("mamba",) * 5 + ("attention",) + ("mamba",) * 4
+    cfg = ModelConfig(name="granite-p", family="hybrid", n_layers=10,
+                      d_model=2048, n_heads=32, n_kv_heads=8, d_ff=8192,
+                      vocab=100352, head_dim=64, ssm_state=128,
+                      layer_types=types_, mamba_n_heads=64,
+                      mamba_conv_bias=True, embedding_multiplier=12.0,
+                      residual_multiplier=0.22, attention_multiplier=1 / 64,
+                      logits_scaling=8.0, rope=False, max_seq=8192)
+    model = Model(cfg)
+
+    def place(tree):
+        return jax.tree_util.tree_map(
+            lambda x: _sds(one_chip, x.shape, x.dtype), tree)
+
+    params = place(jax.eval_shape(model.init_params, jax.random.PRNGKey(0)))
+    cache = place(jax.eval_shape(lambda: model.init_paged_cache(
+        32, 8192, n_blocks=4096, block_size=16)))
+    i32 = lambda *s: _sds(one_chip, s, jnp.int32)  # noqa: E731
+    chunk = ContinuousEngine._make_chunk_fn(types.SimpleNamespace(
+        model=model, max_seq=8192, chunk=8))
+    compiled = chunk.lower(params, cache, i32(32), i32(32),
+                           _sds(one_chip, (32, 2), jnp.uint32),
+                           _sds(one_chip, (32,)), i32(32),
+                           i32(32, 8192 // 16)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "bf16[1,32,8192,8,64]" not in text
+    # a layer's Mamba state is 33.5 MB; a period's would be 302 MB
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.5e8
 
 
 def test_paged_kernel_engages_only_for_a_pool_on_one_tpu(topo, one_chip):
