@@ -12,9 +12,11 @@ must be within ``LOGIT_RTOL`` of the top logit at its position.  The dense
 where the two engines part, the top-2 logit margin there is printed.
 Then the six ``dpia-pallas`` ops and the hand-written matmul, rmsnorm and
 flash-attention kernels run compiled for the chip (``interpret=False``)
-against ``repro.kernels.ref``, and the paged decode kernel against the jnp
+against ``repro.kernels.ref``; the flash kernel at the yi and granite
+cells' longest prefill buckets against the jnp attention it replaces,
+timed alone at several tiles; and the paged decode kernel against the jnp
 attention over the gathered view it replaces, at the page pools of the
-benchmark's two cells; a decode step's attention over every layer is then
+benchmark's cells; a decode step's attention over every layer is then
 timed at the kernel's block of pages and at twice and four times it.
 
 ``--chips 4``: ``ShardedEngine`` over a ``data=4`` mesh serves the same
@@ -75,6 +77,17 @@ PAGED_SIZES = ({"name": "qwen3-4b", "layers": 36, "pages": 528,
                 "kv_heads": 8, "max_seq": 8192, "slots": 32, "head_dim": 64,
                 "scale": 1 / 64})
 PAGED_SLOTS, PAGED_HEADS, PAGED_HEAD_DIM, PAGED_BLOCK = 8, 32, 128, 16
+# prefill attention at the cells' longest buckets: yi-9b-l24's 32/4 heads
+# of 128 over 4096 positions, granite-4.0-h-micro's 32/8 heads of 64 over
+# 8192 scaled by 1/64; each prompt 100 tokens short of its bucket.  The
+# flash kernel is timed at each (bq, bk) and against the jnp path it
+# replaces (models.attention.chunked_attention)
+PREFILL_SIZES = ({"name": "yi-9b-l24", "heads": 32, "kv_heads": 4,
+                  "head_dim": 128, "tokens": 4096},
+                 {"name": "granite-4.0-h-micro", "heads": 32, "kv_heads": 8,
+                  "head_dim": 64, "tokens": 8192, "scale": 1 / 64})
+PREFILL_BLOCKS = ((64, 512), (128, 256), (128, 512), (128, 1024),
+                  (256, 256), (256, 512), (256, 1024), (512, 512))
 # a decode step's slot lengths, as shares of max_seq, for every 8 slots:
 # six busy slots at 22-40% of it, two parked
 STEP_LEN_SHARES = (0.28, 0.34, 0.22, 0.4, 0.3, 0.34, 0.0, 0.0)
@@ -378,9 +391,80 @@ def paged_decode_check(shape: dict, close, interpret: bool) -> None:
             f"bytes")
 
 
+def prefill_attention_check(shape: dict, close, interpret: bool,
+                            blocks=PREFILL_BLOCKS) -> None:
+    """The flash kernel at one cell's prefill shape against the jnp
+    attention it replaces (``models.attention.chunked_attention``, bf16 in
+    and float32 statistics) and against float32 ``ref.flash_attention``,
+    rows past the prompt's length left out; then the kernel alone timed at
+    each (bq, bk) of ``blocks`` that tiles, next to the jnp path."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.kernels import ref
+    from repro.kernels.flash_attention import flash_attention
+    from repro.models.attention import chunked_attention
+
+    nh, nkv, hd, s = (shape["heads"], shape["kv_heads"], shape["head_dim"],
+                      shape["tokens"])
+    scale = shape.get("scale", hd ** -0.5)
+    n = s - 100
+    ks = jax.random.split(jax.random.PRNGKey(SEED + 2), 3)
+    q = jax.random.normal(ks[0], (1, s, nh, hd), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (1, s, nkv, hd), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (1, s, nkv, hd), jnp.bfloat16)
+    heads = lambda t: t[0].transpose(1, 0, 2)  # noqa: E731
+    lengths = jnp.full((nkv,), n, jnp.int32)
+
+    def kernel(q, k, v, **kw):
+        return flash_attention(heads(q), heads(k), heads(v), lengths,
+                               scale=scale, interpret=interpret, **kw)
+
+    def jnp_path(q, k, v):
+        o = chunked_attention(q, k, v, scale=scale, kv_chunk=min(s, 1024))
+        return heads(o.astype(q.dtype))
+
+    got = jax.jit(kernel)(q, k, v)[:, :n]
+    close(f"pallas flash_attention ({shape['name']} prefill, {n} of {s}) "
+          f"against the jnp path", got, lambda: jax.jit(jnp_path)(q, k, v)[
+              :, :n], 2e-2)
+    f32 = lambda t: heads(t).astype(jnp.float32)  # noqa: E731
+    close(f"pallas flash_attention ({shape['name']} prefill) against the "
+          f"float32 reference", got,
+          lambda: ref.flash_attention(f32(q), f32(k), f32(v),
+                                      scale=scale)[:, :n], 2e-2)
+    if interpret:
+        return
+    flops = 4 * nh * hd * n * (n + 1) // 2
+    reps = 20
+
+    def timed(fn, what):
+        fn = jax.jit(fn)
+        out = fn(q, k, v)
+        if not np.isfinite(np.asarray(out[:, :n], np.float32)).all():
+            fail(f"{what}: non-finite output")
+        t = time.perf_counter()
+        for _ in range(reps):
+            out = fn(q, k, v)
+        jax.block_until_ready(out)
+        ms = (time.perf_counter() - t) / reps * 1e3
+        log(f"{what} ({shape['name']}, {n} of {s} positions): {ms:.3f} ms, "
+            f"{flops / ms / 1e9:.1f} TFLOP/s of causal attention")
+
+    timed(jnp_path, "jnp chunked attention")
+    group = nh // nkv
+    for bq, bk in blocks:
+        if s % bq or s % bk or group * bq > 4096:
+            continue
+        timed(functools.partial(kernel, bq=bq, bk=bk),
+              f"pallas flash_attention bq={bq} bk={bk}")
+    timed(kernel, "pallas flash_attention, default blocks")
+
+
 def phase_kernels(clock: CompileClock, sizes=OP_SIZES,
                   ksizes=KERNEL_SIZES, psizes=PAGED_SIZES,
-                  interpret: bool = False) -> list:
+                  fsizes=PREFILL_SIZES, interpret: bool = False) -> list:
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -450,10 +534,14 @@ def phase_kernels(clock: CompileClock, sizes=OP_SIZES,
     close("pallas flash_attention (bf16, causal)",
           flash_attention(q, kv[0], kv[1], interpret=interpret),
           lambda: ref.flash_attention(f32(q), f32(kv[0]), f32(kv[1])), 2e-2)
+    for shape in fsizes:
+        prefill_attention_check(shape, close, interpret)
+        gc.collect()
     for shape in psizes:
         paged_decode_check(shape, close, interpret)
         gc.collect()
     ran += ["pallas matmul", "pallas rmsnorm", "pallas flash_attention",
+            "pallas flash_attention (prefill)",
             "pallas paged_decode_attention"]
     check_kernel_counters("pallas kernels")
     clock.phase("kernels", c0, t0)

@@ -12,7 +12,7 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import ops, paged_decode
+from repro.kernels import flash_attention, paged_decode
 from .common import ModelConfig, apply_rope, init_dense, rmsnorm, rope_freqs
 
 
@@ -141,33 +141,126 @@ def chunked_attention(q, k, v, *, causal: bool = True, q_offset: int = 0,
     return out
 
 
-def attention(p: AttnParams, cfg: ModelConfig, x, positions):
+def _chunked(s: int) -> bool:
+    """Whether the jnp path over ``s`` positions is :func:`chunked_attention`:
+    the sequence exceeds ``CHUNKED_THRESHOLD`` and splits into ``KV_CHUNK``
+    chunks."""
+    return s > CHUNKED_THRESHOLD and s % KV_CHUNK == 0
+
+
+def _attend_jnp(cfg: ModelConfig, q, k, v):
+    """Causal attention in jnp: :func:`chunked_attention` where
+    :func:`_chunked`, else dense float32 scores.  Returns float32
+    (b, s, nh, hd)."""
+    b, s, nh, hd = q.shape
+    nkv = k.shape[2]
+    if _chunked(s):
+        return chunked_attention(q, k, v, causal=True,
+                                 scale=cfg.attention_multiplier)
+    group = nh // nkv
+    qg = q.reshape(b, s, nkv, group, hd)
+    scores = _scaled(jnp.einsum("bsngh,btnh->bngst",
+                                qg.astype(jnp.float32),
+                                k.astype(jnp.float32)), cfg)
+    mask = jnp.tril(jnp.ones((s, s), bool))
+    scores = jnp.where(mask[None, None, None], scores, -jnp.inf)
+    probs = jax.nn.softmax(scores, axis=-1)
+    return jnp.einsum("bngst,btnh->bsngh", probs,
+                      v.astype(jnp.float32)).reshape(b, s, nh, hd)
+
+
+# platforms whose lowering runs prefill attention through the Pallas flash
+# kernel (kernels/flash_attention.py); elsewhere the jnp paths run
+FLASH_PLATFORMS = ("tpu",)
+
+
+def _flash_blocks(cfg: ModelConfig, s: int):
+    return flash_attention.block_sizes(s, s, cfg.n_heads // cfg.n_kv_heads)
+
+
+def _on_one_device(x) -> bool:
+    """Whether ``x`` is known to live on one device: neither it nor the
+    context names a mesh of several.  A Mosaic kernel is not partitioned
+    over a mesh, so a program sharded over one keeps the jnp paths."""
+    sharding = getattr(getattr(x, "aval", None), "sharding", None)
+    meshes = (getattr(sharding, "mesh", None),
+              jax.sharding.get_abstract_mesh())
+    return all(m is None or m.size <= 1 for m in meshes)
+
+
+def prefill_attention(cfg: ModelConfig, s: int, platform: str,
+                      n_devices: int = 1) -> str:
+    """How :func:`attend` runs over ``s`` positions of a program on
+    ``n_devices`` devices of ``platform``: ``"flash_kernel"`` (the Pallas
+    kernel), ``"chunked"`` (:func:`chunked_attention`) or ``"dense"``
+    (float32 scores)."""
+    if (platform in FLASH_PLATFORMS and n_devices == 1
+            and _flash_blocks(cfg, s) is not None):
+        return "flash_kernel"
+    return "chunked" if _chunked(s) else "dense"
+
+
+def _flash(cfg: ModelConfig, dtype, jnp_path):
+    """The kernel path of :func:`attend`; its gradient is ``jnp_path``'s,
+    so a training step through it differentiates."""
+    @jax.custom_vjp
+    def run(q, k, v, lengths):
+        b, s, nh, hd = q.shape
+        nkv = k.shape[2]
+
+        def heads(t):
+            return t.transpose(0, 2, 1, 3).reshape(-1, s, hd)
+        out = flash_attention.flash_attention(
+            heads(q), heads(k), heads(v), jnp.repeat(lengths, nkv),
+            scale=cfg.attention_multiplier)
+        return out.reshape(b, nh, s, hd).transpose(0, 2, 1, 3).astype(dtype)
+
+    def fwd(q, k, v, lengths):
+        return run(q, k, v, lengths), (q, k, v)
+
+    def bwd(res, g):
+        return (*jax.vjp(jnp_path, *res)[1](g), None)
+
+    run.defvjp(fwd, bwd)
+    return run
+
+
+def attend(cfg: ModelConfig, q, k, v, lengths=None, *, dtype):
+    """Causal self-attention of roped q (b, s, nh, hd) over k/v
+    (b, s, nkv, hd); ``lengths`` (b,): each row's real tokens (all ``s``
+    by default).  Returns (b, s, nh, hd) in ``dtype``.
+
+    Where the program is lowered for a TPU (:data:`FLASH_PLATFORMS`), is
+    not sharded over a mesh, and ``s`` tiles by the kernel's blocks, the
+    Pallas flash kernel runs: bf16 on the MXU, float32 statistics, no work
+    above the diagonal or past ``lengths``.  Elsewhere the jnp paths run
+    (:func:`_attend_jnp`), which ignore ``lengths``: rows past a length
+    are padding either way.  :func:`prefill_attention` names the path."""
+    b, s = q.shape[:2]
+
+    def jnp_path(q, k, v, lengths=None):
+        return _attend_jnp(cfg, q, k, v).astype(dtype)
+
+    if _flash_blocks(cfg, s) is None or not _on_one_device(q):
+        return jnp_path(q, k, v)
+    if lengths is None:
+        lengths = jnp.full((b,), s, jnp.int32)
+    kernel = _flash(cfg, dtype, jnp_path)
+    return jax.lax.platform_dependent(
+        q, k, v, jnp.asarray(lengths, jnp.int32), default=jnp_path,
+        **{p: kernel for p in FLASH_PLATFORMS})
+
+
+def _project_out(p: AttnParams, out):
+    """(b, s, nh, hd) attention output -> (b, s, d) through ``wo``."""
+    b, s = out.shape[:2]
+    return jnp.einsum("bsh,hd->bsd", out.reshape(b, s, -1), p.wo)
+
+
+def attention(p: AttnParams, cfg: ModelConfig, x, positions, lengths=None):
     """Full self-attention over x (training / prefill without cache)."""
-    b, s, d = x.shape
-    nh, nkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
     q, k, v = _project_qkv(p, cfg, x, positions)
-    if cfg.use_flash:
-        qf = q.transpose(0, 2, 1, 3).reshape(b * nh, s, hd)
-        kf = k.transpose(0, 2, 1, 3).reshape(b * nkv, s, hd)
-        vf = v.transpose(0, 2, 1, 3).reshape(b * nkv, s, hd)
-        of = ops.flash_attention(qf, kf, vf, causal=True, impl="pallas")
-        out = of.reshape(b, nh, s, hd).transpose(0, 2, 1, 3)
-    elif s > CHUNKED_THRESHOLD and s % KV_CHUNK == 0:
-        out = chunked_attention(q, k, v, causal=True,
-                                scale=cfg.attention_multiplier)
-    else:
-        group = nh // nkv
-        qg = q.reshape(b, s, nkv, group, hd)
-        scores = _scaled(jnp.einsum("bsngh,btnh->bngst",
-                                    qg.astype(jnp.float32),
-                                    k.astype(jnp.float32)), cfg)
-        mask = jnp.tril(jnp.ones((s, s), bool))
-        scores = jnp.where(mask[None, None, None], scores, -jnp.inf)
-        probs = jax.nn.softmax(scores, axis=-1)
-        out = jnp.einsum("bngst,btnh->bsngh", probs,
-                         v.astype(jnp.float32)).reshape(b, s, nh, hd)
-    out = out.astype(x.dtype).reshape(b, s, nh * hd)
-    return jnp.einsum("bsh,hd->bsd", out, p.wo)
+    return _project_out(p, attend(cfg, q, k, v, lengths, dtype=x.dtype))
 
 
 class KVCache(NamedTuple):
@@ -182,16 +275,17 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int) -> KVCache:
 
 
 def attention_prefill(p: AttnParams, cfg: ModelConfig, x, cache: KVCache,
-                      start: int = 0):
-    """Prefill: run full attention AND fill the cache at [start, start+s)."""
+                      start: int = 0, lengths=None):
+    """Prefill: run full attention AND fill the cache at [start, start+s);
+    ``lengths`` (b,): real tokens of each row within x."""
     b, s, _ = x.shape
     positions = start + jnp.arange(s)[None, :]
     q, k, v = _project_qkv(p, cfg, x, positions)
     new_cache = KVCache(
         jax.lax.dynamic_update_slice(cache.k, k, (0, start, 0, 0)),
         jax.lax.dynamic_update_slice(cache.v, v, (0, start, 0, 0)))
-    out = attention(p, cfg, x, positions)
-    return out, new_cache
+    out = attend(cfg, q, k, v, lengths, dtype=x.dtype)
+    return _project_out(p, out), new_cache
 
 
 def _attend_token(cfg: ModelConfig, q, k_l, v_l, pos, per_slot: bool,
@@ -380,7 +474,7 @@ def attention_prefill_cached(p: AttnParams, cfg: ModelConfig, x,
 
 
 def paged_attention_prefill(p: AttnParams, cfg: ModelConfig, x, ck, cv, li,
-                            bt_row, start, *, first: bool):
+                            bt_row, start, *, first: bool, lengths=None):
     """Prefill one prompt chunk into LAYER-STACKED page pools.
 
     x: (1, s, d); ck/cv: (L, n_blocks, block_size, rows, lanes); bt_row: the
@@ -392,7 +486,9 @@ def paged_attention_prefill(p: AttnParams, cfg: ModelConfig, x, ck, cv, li,
     within x exactly like the dense :func:`attention_prefill` (bitwise the
     same computation, which is what keeps a paged engine token-identical to
     the dense oracle for prompts that fit one chunk); continuation chunks
-    gather the slot's pages and attend masked by ``kpos <= qpos``."""
+    gather the slot's pages and attend masked by ``kpos <= qpos``.
+    ``lengths`` (1,): the chunk's real tokens, which the first chunk's
+    kernel path (:func:`attend`) reads to skip the padding."""
     b, s, _ = x.shape
     nb, bs = ck.shape[1], ck.shape[2]
     start = jnp.asarray(start, jnp.int32)
@@ -404,8 +500,8 @@ def paged_attention_prefill(p: AttnParams, cfg: ModelConfig, x, ck, cv, li,
     cv = cv.at[li, page, off].set(_to_rows(cfg, v[0]).astype(cv.dtype),
                                   mode="drop")
     if first:
-        out = attention(p, cfg, x, positions)
-        return out, ck, cv
+        out = attend(cfg, q, k, v, lengths, dtype=x.dtype)
+        return _project_out(p, out), ck, cv
     k_l = jax.lax.dynamic_index_in_dim(ck, li, axis=0, keepdims=False)
     v_l = jax.lax.dynamic_index_in_dim(cv, li, axis=0, keepdims=False)
     k_all = _to_heads(cfg, _gather_pages(k_l, bt_row[None]))

@@ -61,7 +61,6 @@ class ModelConfig:
     remat: bool = True
     fsdp: bool = False                  # ZeRO-style param+opt sharding on data
     opt_8bit: bool = False              # 8-bit Adam moments (100B+ configs)
-    use_flash: bool = False             # pallas flash attention (TPU target)
     max_seq: int = 4096
 
     @property

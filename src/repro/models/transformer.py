@@ -465,9 +465,12 @@ class Model:
 
                 def attn_fn(a, ap, xn):
                     nonlocal kv
-                    run = (attn_mod.attention_prefill_cached if attend_cache
-                           else attn_mod.attention_prefill)
-                    y, nkv = run(ap, cfg, xn, _at(kv, gi, a), start)
+                    if attend_cache:
+                        y, nkv = attn_mod.attention_prefill_cached(
+                            ap, cfg, xn, _at(kv, gi, a), start)
+                    else:
+                        y, nkv = attn_mod.attention_prefill(
+                            ap, cfg, xn, _at(kv, gi, a), start, lengths)
                     kv = _set(kv, gi, a, nkv)
                     return y
                 x = self._run_period(hp, gi, x, mamba_fn, attn_fn)
@@ -487,7 +490,7 @@ class Model:
                         layer.attn, cfg, h_in, kv, start)
                 else:
                     y_attn, new_kv = attn_mod.attention_prefill(
-                        layer.attn, cfg, h_in, kv, start)
+                        layer.attn, cfg, h_in, kv, start, lengths)
                 h = x + y_attn
                 y = rmsnorm(h, layer.ln2, cfg.norm_eps)
                 if cfg.n_experts:
@@ -556,7 +559,7 @@ class Model:
                     nonlocal ck, cv
                     y, ck, cv = attn_mod.paged_attention_prefill(
                         ap, cfg, xn, ck, cv, g * sites + a, bt_row, start,
-                        first=first)
+                        first=first, lengths=lengths)
                     return y
                 x = self._run_period(hp, g, x, mamba_fn, attn_fn)
                 return (x, ck, cv, st), None
@@ -570,7 +573,7 @@ class Model:
                     h_in = rmsnorm(x, layer.ln1, cfg.norm_eps)
                     y, ck, cv = attn_mod.paged_attention_prefill(
                         layer.attn, cfg, h_in, ck, cv, li, bt_row, start,
-                        first=first)
+                        first=first, lengths=lengths)
                 h = x + y
                 with jax.named_scope("mlp"):
                     z = rmsnorm(h, layer.ln2, cfg.norm_eps)

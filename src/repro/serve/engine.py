@@ -184,6 +184,27 @@ def decode_attention(model: Model, cache, block_tables) -> str:
             else "view")
 
 
+def prefill_attention(model: Model, params, bucket: int,
+                      first: bool) -> Optional[str]:
+    """How a prefill chunk of ``bucket`` positions attends
+    (:func:`repro.models.attention.prefill_attention`): a first chunk
+    attends within itself, through ``"flash_kernel"`` where the weights
+    live on one TPU device and the bucket tiles, else ``"chunked"`` or
+    ``"dense"``; a continuation chunk attends over the slot's cached
+    positions, ``"dense"``.  None for a model without attention."""
+    if model.cfg.family == "ssm":
+        return None
+    if not first:
+        return "dense"
+    sharding = getattr(jax.tree_util.tree_leaves(params)[0], "sharding",
+                       None)
+    devices = (sharding.device_set if sharding is not None
+               else {jax.devices()[0]})
+    return attn_mod.prefill_attention(model.cfg, bucket,
+                                      next(iter(devices)).platform,
+                                      len(devices))
+
+
 class _DecodeChunk:
     """The jitted decode chunk, one program per attention path.  A call
     takes the path as the static ``paged_kernel`` (False: the view or dense
@@ -1272,14 +1293,20 @@ class ContinuousEngine(_EngineBase):
         start = self.sched.slots[slot].prefill_pos
         take = min(plen - start, self.buckets[-1])
         bucket = pick_bucket(take, self.buckets)
+        attn = prefill_attention(self.model, self.params, bucket,
+                                 start == 0)
         with obs.span("serve.prefill_chunk", slot=slot, req_id=rid,
                       bucket=bucket, start=start,
+                      **({"attn": attn} if attn else {}),
                       **({"ssm": "chunked"} if self.model.ssm_layers()
                          else {})):
             if start == 0:
                 self._begin_admit(slot)
-            if not self._prefill_advance_inner(slot, r, plen, start, take,
-                                               bucket):
+            advanced = self._prefill_advance_inner(slot, r, plen, start,
+                                                   take, bucket)
+            if attn == "flash_kernel":
+                obs.counter("serve.prefill_attn.flash_kernel").inc()
+            if not advanced:
                 return False
             with obs.span("serve.finish_admit", slot=slot, req_id=rid):
                 return self._finish_admit(slot, rid)

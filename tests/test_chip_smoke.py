@@ -18,6 +18,10 @@ LENS = (8, 12, 20, 33, 40)
 OPS = {"n": 8192, "mm": (128, 256, 384), "rows": 64, "d": 256}
 KERNELS = {"tokens": 128, "d": 256, "ff": 512, "heads": 4, "kv_heads": 2,
            "head_dim": 128}
+PREFILL = ({"name": "gqa8", "heads": 8, "kv_heads": 1, "head_dim": 128,
+            "tokens": 512},
+           {"name": "packed64", "heads": 8, "kv_heads": 2, "head_dim": 64,
+            "tokens": 256, "scale": 1 / 64})
 PAGED = ({"name": "gqa4", "layers": 3, "pages": 24, "kv_heads": 8,
           "max_seq": 160},
          {"name": "packed64", "layers": 2, "pages": 40, "kv_heads": 8,
@@ -38,11 +42,13 @@ def test_one_chip_phases(capsys):
     clock = smoke.CompileClock()
     smoke.phase_serve(clock, smoke=True, lens=LENS)
     ran = smoke.phase_kernels(clock, sizes=OPS, ksizes=KERNELS,
-                              psizes=PAGED, interpret=True)
+                              psizes=PAGED, fsizes=PREFILL,
+                              interpret=True)
     out = capsys.readouterr().out
     assert ran == ["dot", "asum", "scal", "matmul", "rmsnorm", "softmax",
                    "pallas matmul", "pallas rmsnorm",
                    "pallas flash_attention",
+                   "pallas flash_attention (prefill)",
                    "pallas paged_decode_attention"]
     n = len(LENS) * smoke.MAX_NEW
     assert f"ContinuousEngine(paged): teacher-forced through Model.forward, " \

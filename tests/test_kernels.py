@@ -43,20 +43,43 @@ def test_pallas_rmsnorm(rng, rows, d, br, dtype):
                                rtol=tol, atol=tol)
 
 
-@pytest.mark.parametrize("bh,bkv,s,d,bq,bk_", [
-    (4, 4, 128, 64, 64, 64),     # MHA
-    (8, 2, 256, 64, 64, 128),    # GQA 4:1
-    (4, 1, 128, 32, 128, 32),    # MQA
+@pytest.mark.parametrize("bh,bkv,s,d,bq,bk_,opts", [
+    pytest.param(4, 4, 128, 64, 64, 64, {}, id="4-4-128-64-64-64"),  # MHA
+    pytest.param(8, 2, 256, 64, 64, 128, {},
+                 id="8-2-256-64-64-128"),                          # GQA 4:1
+    pytest.param(4, 1, 128, 32, 128, 32, {}, id="4-1-128-32-128-32"),  # MQA
+    # GQA 8:1 at head_dim 128 (yi-9b), up to four kv tiles a q block
+    pytest.param(32, 4, 512, 128, 64, 128, {}, id="gqa8-hd128-tiled"),
+    # GQA 4:1 at head_dim 64 with granite's score scale
+    pytest.param(16, 4, 512, 64, 128, 128, {"scale": 1 / 64},
+                 id="gqa4-hd64-scale"),
+    # a real length short of the bucket: a q block and kv tiles past it
+    # are skipped; rows past it are not compared
+    pytest.param(16, 2, 512, 128, 128, 128, {"length": 300},
+                 id="length-300-of-512"),
+    pytest.param(16, 2, 512, 128, 64, 128, {"dtype": "bfloat16"},
+                 id="bf16"),
+    # the default tiles (block_sizes): 128 queries of 8 heads, 512 keys
+    pytest.param(16, 2, 1024, 128, None, None, {"dtype": "bfloat16"},
+                 id="bf16-default-blocks"),
 ])
 @pytest.mark.parametrize("causal", [True, False])
-def test_pallas_flash_attention(rng, bh, bkv, s, d, bq, bk_, causal):
-    q = jnp.asarray(rng.randn(bh, s, d), "float32") * 0.3
-    k = jnp.asarray(rng.randn(bkv, s, d), "float32") * 0.3
-    v = jnp.asarray(rng.randn(bkv, s, d), "float32")
-    got = flash_attention(q, k, v, causal=causal, bq=bq, bk=bk_)
-    want = ref.flash_attention(q, k, v, causal=causal)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-3, atol=2e-3)
+def test_pallas_flash_attention(rng, bh, bkv, s, d, bq, bk_, opts, causal):
+    dtype = opts.get("dtype", "float32")
+    q = jnp.asarray(rng.randn(bh, s, d) * 0.3, dtype)
+    k = jnp.asarray(rng.randn(bkv, s, d) * 0.3, dtype)
+    v = jnp.asarray(rng.randn(bkv, s, d), dtype)
+    n = opts.get("length", s)
+    lengths = jnp.full((bkv,), n, jnp.int32) if n < s else None
+    got = flash_attention(q, k, v, lengths, causal=causal,
+                          scale=opts.get("scale"), bq=bq, bk=bk_)
+    assert got.dtype == q.dtype
+    f32 = lambda t: t[:, :n].astype("float32")  # noqa: E731
+    want = ref.flash_attention(f32(q), f32(k), f32(v), causal=causal,
+                               scale=opts.get("scale"))
+    tol = 2e-3 if dtype == "float32" else 2e-2
+    np.testing.assert_allclose(np.asarray(f32(got)), np.asarray(want),
+                               rtol=tol, atol=tol)
 
 
 def test_pallas_flash_decode_offset(rng):

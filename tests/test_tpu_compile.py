@@ -86,13 +86,76 @@ def test_hand_written_kernel_compiles(one_chip, kernel):
             _sds(one_chip, (KV_HEADS, TOKENS, HEAD_DIM), bf))
 
 
-def test_flash_attention_refuses_kv_beyond_vmem(one_chip):
+# (query heads, kv heads, queries, keys, head_dim, scale): 32768 keys of
+# 128 lanes (8 MiB of K and V a kv head, past what a VMEM-resident kernel
+# could hold), yi-9b-l24's 4096-bucket prefill, granite-4.0-h-micro's
+# 8192-bucket prefill (heads of 64, scores scaled by 1/64)
+_FLASH_SHAPES = [("kv-32768", HEADS, KV_HEADS, 128, 32768, HEAD_DIM, None),
+                 ("yi-9b-l24-4096", 32, 4, 4096, 4096, 128, None),
+                 ("granite-8192", 32, 8, 8192, 8192, 64, 1 / 64)]
+
+
+@pytest.mark.parametrize("nh,nkv,sq,sk,hd,scale",
+                         [s[1:] for s in _FLASH_SHAPES],
+                         ids=[s[0] for s in _FLASH_SHAPES])
+def test_flash_attention_compiles_tiled_over_kv(one_chip, nh, nkv, sq, sk,
+                                                hd, scale):
     bf = jnp.bfloat16
-    with pytest.raises(ValueError, match="VMEM"):
-        jax.jit(lambda q, k, v: flash_attention(q, k, v, interpret=False)
-                ).lower(_sds(one_chip, (HEADS, 128, HEAD_DIM), bf),
-                        _sds(one_chip, (KV_HEADS, 32768, HEAD_DIM), bf),
-                        _sds(one_chip, (KV_HEADS, 32768, HEAD_DIM), bf))
+    _kernel_hlo(
+        lambda q, k, v, n: flash_attention(q, k, v, n, scale=scale,
+                                           q_offset=sk - sq,
+                                           interpret=False),
+        _sds(one_chip, (nh, sq, hd), bf), _sds(one_chip, (nkv, sk, hd), bf),
+        _sds(one_chip, (nkv, sk, hd), bf), _sds(one_chip, (nkv,),
+                                                jnp.int32))
+
+
+def test_prefill_attention_takes_the_kernel_only_on_one_tpu(topo, one_chip):
+    """``attention()`` lowered for one described v5e runs the flash
+    kernel; over a mesh of four it keeps the jnp path (a Mosaic kernel is
+    not partitioned) and still compiles."""
+    from repro.launch.mesh import make_mesh
+    cfg = ModelConfig(name="yi-like", family="dense", n_layers=1,
+                      d_model=512, n_heads=32, n_kv_heads=4, head_dim=128,
+                      d_ff=1024, vocab=256)
+    params = jax.eval_shape(lambda k: attention.init_attn(k, cfg),
+                            jax.random.PRNGKey(0))
+    mesh = make_mesh((4,), ("data",), devices=topo.devices)
+    for sharding, kernel in ((one_chip, True),
+                             (NamedSharding(mesh, PartitionSpec()), False)):
+        p = jax.tree_util.tree_map(
+            lambda x: _sds(sharding, x.shape, x.dtype), params)
+        text = jax.jit(lambda p, x: attention.attention(
+            p, cfg, x, jnp.arange(1024)[None])).lower(
+            p, _sds(sharding, (1, 1024, 512), jnp.bfloat16)).compile(
+            ).as_text()
+        assert ("tpu_custom_call" in text) == kernel
+
+
+def test_yi_prefill_runs_the_flash_kernel_for_v5e(one_chip):
+    """Two layers at yi-9b-l24's widths, a paged first chunk of 4096: the
+    program runs the kernel, and the chunked path's float32 scores of a
+    1024-key chunk, (1, 4096, 4, 8, 1024), are not in it."""
+    model = Model(ModelConfig(name="yi-9b-l2", family="dense", n_layers=2,
+                              d_model=4096, n_heads=32, n_kv_heads=4,
+                              head_dim=128, d_ff=11008, vocab=64000,
+                              max_seq=4096))
+
+    def place(tree):
+        return jax.tree_util.tree_map(
+            lambda x: _sds(one_chip, x.shape, x.dtype), tree)
+
+    params = place(jax.eval_shape(model.init_params, jax.random.PRNGKey(0)))
+    kv, _ = model.split_paged_cache(place(jax.eval_shape(
+        lambda: model.init_paged_cache(1, 4096, n_blocks=257,
+                                       block_size=16))))
+    i32 = lambda *s: _sds(one_chip, s, jnp.int32)  # noqa: E731
+    text = jax.jit(lambda p, t, kv, row, s, n: model.prefill_paged(
+        p, t, kv, row, None, s, n, first=True)).lower(
+        params, i32(1, 4096), kv, i32(256), i32(), i32(1)).compile(
+        ).as_text()
+    assert "tpu_custom_call" in text
+    assert "f32[1,4096,4,8,1024]" not in text
 
 
 _DPIA_OPS = [
